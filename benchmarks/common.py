@@ -84,16 +84,12 @@ def emit_json(filename, payload):
 def compiled_cost(fn, *shaped_args):
     """Lower+compile ``fn`` on ShapeDtypeStructs and return
     (bytes_accessed, flops) from XLA's post-optimization cost_analysis —
-    the structural numbers the one-pass-vs-multi-pass assertions use
-    (jax<0.5 returns one dict per partition; we take the first)."""
+    the structural numbers the one-pass-vs-multi-pass assertions use."""
     shaped = [jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
         for a in shaped_args]
     c = jax.jit(fn).lower(*shaped).compile()
-    ca = c.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = c.cost_analysis() or {}
     bytes_ = float(ca.get("bytes accessed", -1.0))
     if bytes_ <= 0:
         # fail loudly rather than let the one-pass assertions compare

@@ -68,6 +68,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     all_mods, smoke_mods = _modules()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = smoke_mods if args.smoke else all_mods
     if args.only:
         byname = {m.__name__.rsplit(".", 1)[-1]: m for m in all_mods}

@@ -23,7 +23,7 @@ Walks a :class:`ClosedJaxpr` (the traced train step) propagating a
   for key-reuse detection); static slices of split outputs derive distinct
   child identities.
 
-Sub-jaxprs (pjit, scan, while, cond, custom_jvp/vjp, remat) are interpreted
+Sub-jaxprs (jit, scan, while, cond, custom_jvp/vjp, remat) are interpreted
 recursively; scan/while carries run to a join fixpoint with event counting
 disabled, then one final counting pass.
 
@@ -37,6 +37,7 @@ import itertools
 from typing import Any, Callable, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var
+from jax.extend.core import primitives as _prims
 
 try:                                    # readable "prim @ file:line" provenance
     from jax._src import source_info_util
@@ -656,9 +657,17 @@ def _find_sub_jaxpr(params) -> Optional[ClosedJaxpr]:
     return None
 
 
-@handler("pjit", "closed_call", "core_call", "remat", "checkpoint",
-         "custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
-         "custom_vjp_call_jaxpr", "remat2")
+# the call-like primitives that carry a sub-jaxpr, under the names the
+# installed jax gives them (a nested jit is "jit", jax.checkpoint "remat2").
+# shard_map's body keeps the global dims' positions (each device sees a
+# slice of them), so it interprets like a call; jax.extend does not export
+# its primitive
+_CALL_PRIMITIVES = tuple(p.name for p in (
+    _prims.jit_p, _prims.closed_call_p, _prims.call_p, _prims.remat_p,
+    _prims.custom_jvp_call_p, _prims.custom_vjp_call_p)) + ("shard_map",)
+
+
+@handler(*_CALL_PRIMITIVES)
 def _call_rule(interp, eqn, ins, count):
     sub = _find_sub_jaxpr(eqn.params)
     if sub is None:

@@ -68,12 +68,23 @@ class ShardingConstraints:
                the same data axes the full batch arrived on, so the scanned
                backward stays data-parallel instead of degrading to the
                GSPMD default.  Only streaming engines consume it.
+    kernel_map — runs a Pallas kernel call on every device of the mesh:
+               ``kernel_map(fn, rows=k)(*args)``, where the first ``k``
+               arguments are split over the data axes along their leading
+               (example) axis and everything else is replicated, returns
+               the sum over devices of ``fn`` on each device's share — so
+               ``fn`` must add up over rows (a clipped sum does; with
+               ``rows=0`` every device computes the whole result).  GSPMD
+               cannot partition a Mosaic kernel, so on a TPU mesh a kernel
+               compiles only inside it.  The streaming engine and the fused
+               update consume it.
     """
     grad: Optional[Callable] = None
     grad_flat: Optional[Callable] = None
     pe_grad: Optional[Callable] = None
     pe_dtype: Any = None
     tile_batch: Optional[Callable] = None
+    kernel_map: Optional[Callable] = None
 
 
 def _pe_hooks(constraints: Optional[ShardingConstraints]):

@@ -139,6 +139,20 @@ def _microbatched_clipped_sum(loss_fn, params, batch, mask, cfg: DPConfig,
                  "clip_coef": coefs.reshape(-1)}
 
 
+def _stream_tile(state: TrainState, batch_size: int, view: FlatGradView,
+                 constraints: Optional[ShardingConstraints]) -> int:
+    """The streaming tile from the memory budget, net of the train state:
+    the step's input state stays live beside its output (the local
+    executor does not donate), so both count against the device."""
+    from ..launch.costmodel import stream_tile_size
+    state_bytes = 2 * sum(x.size * x.dtype.itemsize
+                          for x in jax.tree.leaves(state))
+    pe_dtype = constraints.pe_dtype if constraints is not None else None
+    return stream_tile_size(
+        batch_size, view.n_params, state_bytes=state_bytes,
+        pe_dtype_bytes=jnp.dtype(pe_dtype or jnp.float32).itemsize)
+
+
 def build_accumulate_fn(loss_fn: Callable, cfg: DPConfig, *,
                         constraints: Optional[ShardingConstraints] = None):
     """accumulate(state, batch, mask) -> (state, metrics). Jit-stable shapes."""
@@ -178,9 +192,11 @@ def build_accumulate_fn(loss_fn: Callable, cfg: DPConfig, *,
             # Pallas kernel inside a scan) — no summed gradient tree, no
             # view.flatten scatter
             fn = clipping.resolve_engine(cfg.engine)
+            tile = cfg.stream_tile or _stream_tile(state, mask.shape[0], view,
+                                                   constraints)
             acc, aux = fn(loss_fn, state.params, batch, mask, cfg.clip_norm,
                           constraints=constraints, acc=state.grad_acc,
-                          view=view, tile=cfg.stream_tile)
+                          view=view, tile=tile)
             if constraints is not None and constraints.grad_flat is not None:
                 acc = constraints.grad_flat(acc)
             metrics = _dp_metrics(aux, mask)
@@ -213,15 +229,18 @@ def build_accumulate_fn(loss_fn: Callable, cfg: DPConfig, *,
     return accumulate
 
 
-def build_update_fn(optimizer: Optimizer, cfg: DPConfig, *, fuse: bool = True):
+def build_update_fn(optimizer: Optimizer, cfg: DPConfig, *, fuse: bool = True,
+                    constraints: Optional[ShardingConstraints] = None):
     """update(state) -> state. Noise + optimizer step + reset accumulator.
 
     SGD/momentum dispatches to the fused
     :func:`repro.kernels.tree_noisy_update` (noise generated and applied in
     one pass over the flat accumulator); other optimizers — and ``fuse=False``,
     the benchmark's multi-pass baseline — materialise the noisy gradient tree
-    and run the generic ``optimizer.update``.
+    and run the generic ``optimizer.update``.  ``constraints.kernel_map``,
+    when set, runs the update kernels on every device of the mesh.
     """
+    kernel_map = constraints.kernel_map if constraints is not None else None
 
     def update(state: TrainState):
         view = FlatGradView.for_tree(state.params)
@@ -239,7 +258,7 @@ def build_update_fn(optimizer: Optimizer, cfg: DPConfig, *, fuse: bool = True):
             params, new_mom = tree_noisy_update(
                 state.params, state.grad_acc, key, sigma_c, denom, lr,
                 momentum_buf=state.opt_state.get("mom"),
-                momentum=hyper["momentum"], view=view)
+                momentum=hyper["momentum"], view=view, kernel_map=kernel_map)
             opt_state = dict(state.opt_state, count=count + 1)
             if new_mom is not None:
                 opt_state["mom"] = new_mom
@@ -280,7 +299,7 @@ def build_fused_step(loss_fn: Callable, optimizer: Optimizer, cfg: DPConfig, *,
     """One logical batch == one call: clip+accumulate then noise+update.
     This is the function lowered in the dry-run."""
     accumulate = build_accumulate_fn(loss_fn, cfg, constraints=constraints)
-    update = build_update_fn(optimizer, cfg)
+    update = build_update_fn(optimizer, cfg, constraints=constraints)
 
     def step(state: TrainState, batch, mask):
         state, metrics = accumulate(state, batch, mask)

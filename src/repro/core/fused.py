@@ -48,16 +48,10 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..kernels import flat_clip_accum, tree_clip_accum
+from ..kernels import flat_clip_accum, interpret_mode, tree_clip_accum
 from ..utils.params import FlatGradView
 from .clipping import (Aux, ShardingConstraints, clip_coef, ghost_norms,
                        per_example_grads_and_sq, register_engine)
-
-
-def _interpret() -> bool:
-    # Pallas lowers natively on TPU; everywhere else run the kernel's
-    # interpret mode (same arithmetic, XLA ops instead of Mosaic)
-    return jax.default_backend() != "tpu"
 
 
 @register_engine("masked_fused", materializes_pe=True)
@@ -69,7 +63,7 @@ def fused_clipped_grads(loss_fn: Callable, params, batch, mask,
     # kernel recomputes mask * min(1, C/norm) internally; coef here is aux
     coef, norms = clip_coef(sq, mask, clip_norm)
     summed = tree_clip_accum(grads, norms, mask, clip_norm,
-                             interpret=_interpret())
+                             interpret=interpret_mode())
     return summed, {"per_example_norms": norms, "clip_coef": coef}
 
 
@@ -153,7 +147,8 @@ def streaming_clipped_grads(loss_fn: Callable, params, batch, mask,
         xs = xs + (resh(norms_all), resh(coef_all))
 
     tile_hook = constraints.tile_batch if constraints is not None else None
-    interpret = _interpret()
+    kernel_map = constraints.kernel_map if constraints is not None else None
+    interpret = interpret_mode()
     pad_d = view.total - view.n_params
     # XLA lowers a width-1 batched backward through a different dot path
     # than the same row inside a wider vmap (the batch dim degenerates),
@@ -190,8 +185,17 @@ def streaming_clipped_grads(loss_fn: Callable, params, batch, mask,
             # the accumulator itself is NEVER padded/copied here — that
             # would break the kernel's input/output aliasing
             tile_flat = jnp.pad(tile_flat, ((0, 0), (0, pad_d)))
-        carry = flat_clip_accum(carry, tile_flat, norms, mk, clip_norm,
-                                interpret=interpret)
+        if kernel_map is None:
+            carry = flat_clip_accum(carry, tile_flat, norms, mk, clip_norm,
+                                    interpret=interpret)
+        else:
+            # on a mesh each device folds its rows of the tile from zero
+            # and the partial sums are all-reduced into the carry
+            carry = carry + kernel_map(
+                lambda g, n, k: flat_clip_accum(
+                    jnp.zeros((g.shape[1],), jnp.float32), g, n, k,
+                    clip_norm, interpret=interpret), rows=3)(
+                        tile_flat, norms, mk)
         # aux reports the tile's m real examples (drop the vmap-width pad)
         return carry, (norms[:m], coef[:m])
 

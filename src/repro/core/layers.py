@@ -25,7 +25,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..kernels import ghost_norm_dense
+from ..kernels import ghost_norm_dense, interpret_mode
 from .tape import LayerSpec, Tape
 
 # Flip to force one ghost-vs-direct path in tests.
@@ -49,7 +49,7 @@ def _norm_tiles(T: int, di: int, do: int):
     """Full 128 (sublane×lane-legal) tiles on TPU — Mosaic cannot lower a
     trailing tile below 128 for f32, the kernel pads instead; shape-fitted
     8-aligned tiles in interpret mode so the padded smoke shapes stay tiny."""
-    if jax.default_backend() == "tpu":
+    if not interpret_mode():
         return (128, 128, 128)
     r8 = lambda n: -(-n // 8) * 8
     return (min(128, r8(di)), min(128, r8(do)), min(128, r8(T)))
@@ -228,7 +228,7 @@ def _sq_norm_dense_one(x, dy, has_bias):
         nw = jnp.sum(gx * gd, axis=(1, 2))
     elif _NORM_BACKEND != "xla":
         nw = ghost_norm_dense(xf, df,
-                              interpret=jax.default_backend() != "tpu",
+                              interpret=interpret_mode(),
                               tiles=_norm_tiles(T, di, do))
     else:
         m = jnp.einsum("bti,bto->bio", xf, df)

@@ -312,12 +312,25 @@ class PrivacySession:
                     state_shape)
             elif name == "update":
                 self._jit_cache[name] = ex.jit_update(build_update_fn(
-                    self.optimizer, self.dp), state_shape)
+                    self.optimizer, self.dp, constraints=self.constraints),
+                    state_shape)
             elif name == "evaluate":
                 self._jit_cache[name] = ex.jit_eval(build_eval_fn(self.loss_fn))
             else:
                 raise KeyError(name)
         return self._jit_cache[name]
+
+    def compiled(self, name: str, batch=None, mask=None):
+        """Ahead-of-time compile of one step program ("accumulate" and
+        "step" for a physical ``batch``/``mask``, "update") exactly as
+        ``fit()`` jits it.  Returns jax's ``Compiled``: its ``as_text()``
+        and ``memory_analysis()`` describe the program ``fit()`` runs."""
+        self._configure_train()
+        fn = self._jitted(name)
+        if name == "update":
+            return fn.lower(self.state).compile()
+        batch, mask = self.executor.place(batch, mask)
+        return fn.lower(self.state, batch, mask).compile()
 
     # -- the DP-SGD lifecycle ----------------------------------------------
 

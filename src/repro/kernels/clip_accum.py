@@ -30,18 +30,30 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TILE_D = 1024
 
 
 def _opaque_count(n: int):
-    # the fold's trip count as a (1, 1) operand XLA cannot constant-fold:
-    # a literal count of 1 would re-unroll the loop and reintroduce the
-    # FMA contraction _fold_rows exists to avoid
-    return jax.lax.optimization_barrier(jnp.full((1, 1), n, jnp.int32))
+    # the fold's trip count as an operand XLA cannot constant-fold: a
+    # literal count of 1 would re-unroll the loop and reintroduce the FMA
+    # contraction _fold_rows exists to avoid
+    return jax.lax.optimization_barrier(jnp.full((1,), n, jnp.int32))
 
 
-def _fold_rows(w, init, n):
+def _clip_rows(g_ref, norm_ref, mask_ref, c_ref, w_ref):
+    # per-example grads arrive in their storage dtype (f32 or bf16 under
+    # pe_bf16) and are upcast per VMEM tile — no f32 HBM copy upstream.
+    # The weighted rows land in VMEM scratch BEFORE the fold, so the fold
+    # is adds only (no multiply left to contract into an FMA)
+    norms = norm_ref[...]                # (B, 1)
+    coef = mask_ref[...] * jnp.minimum(1.0, c_ref[0] / jnp.maximum(norms,
+                                                                   1e-12))
+    w_ref[...] = g_ref[...].astype(jnp.float32) * coef
+
+
+def _fold_rows(w_ref, init, n):
     # strict left fold over the example axis — the engines' CANONICAL
     # reduction order (matches masked_pe's lax.scan fold bitwise, and
     # composes across microbatch tiles, which jnp.sum's XLA-internal reduce
@@ -49,27 +61,40 @@ def _fold_rows(w, init, n):
     # sequential loop primitive (an unrolled python loop lets XLA
     # FMA-contract the row multiply into the adds) AND the DATA-DEPENDENT
     # trip count ``n`` (a static bound of 1 is constant-unrolled and
-    # contracted the same way — observed on XLA:CPU).
+    # contracted the same way — observed on XLA:CPU).  Rows are read from
+    # the ref with ``pl.ds``: Mosaic lowers a dynamic ref slice, not a
+    # dynamic_slice of a value.
     def body(b, a):
-        return a + jax.lax.dynamic_slice_in_dim(w, b, 1, axis=0)
+        return a + w_ref[pl.ds(b, 1), :]
     return jax.lax.fori_loop(0, n, body, init)
 
 
-def _kernel(g_ref, norm_ref, mask_ref, c_ref, n_ref, out_ref):
-    # per-example grads arrive in their storage dtype (f32 or bf16 under
-    # pe_bf16) and are upcast per VMEM tile — no f32 HBM copy upstream
-    g = g_ref[...].astype(jnp.float32)   # (B, TILE_D)
-    norms = norm_ref[...]                # (B, 1)
-    mask = mask_ref[...]                 # (B, 1)
-    c = c_ref[0, 0]
-    coef = mask * jnp.minimum(1.0, c / jnp.maximum(norms, 1e-12))
-    out_ref[...] = _fold_rows(g * coef,
-                              jnp.zeros((1, g.shape[1]), jnp.float32),
-                              n_ref[0, 0])
+def _kernel(g_ref, norm_ref, mask_ref, c_ref, n_ref, out_ref, w_ref):
+    _clip_rows(g_ref, norm_ref, mask_ref, c_ref, w_ref)
+    out_ref[...] = _fold_rows(w_ref, jnp.zeros(out_ref.shape, jnp.float32),
+                              n_ref[0])
+
+
+def _specs(rows: int, tile_d: int):
+    # (grads tile, norms, mask) in VMEM; clip norm and trip count as SMEM
+    # scalars; the weighted-rows scratch the fold reads back
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return ([pl.BlockSpec((rows, tile_d), lambda i: (0, i)),
+             pl.BlockSpec((rows, 1), lambda i: (0, 0)),
+             pl.BlockSpec((rows, 1), lambda i: (0, 0)),
+             smem, smem],
+            [pltpu.VMEM((rows, tile_d), jnp.float32)])
+
+
+def _scalars(norms, mask, clip_norm, rows: int):
+    return (norms.astype(jnp.float32).reshape(rows, 1),
+            mask.astype(jnp.float32).reshape(rows, 1),
+            jnp.asarray(clip_norm, jnp.float32).reshape(1),
+            _opaque_count(rows))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_d"))
-def clip_accum(grads, norms, mask, clip_norm, *, interpret=True,
+def clip_accum(grads, norms, mask, clip_norm, *, interpret: bool,
                tile_d=TILE_D):
     """grads (B, D) f32/bf16; norms (B,); mask (B,); clip_norm -> (D,) f32."""
     B, D = grads.shape
@@ -77,38 +102,27 @@ def clip_accum(grads, norms, mask, clip_norm, *, interpret=True,
     if pad:
         grads = jnp.pad(grads, ((0, 0), (0, pad)))
     Dp = D + pad
+    in_specs, scratch = _specs(B, tile_d)
     out = pl.pallas_call(
         _kernel,
         grid=(Dp // tile_d,),
-        in_specs=[
-            pl.BlockSpec((B, tile_d), lambda i: (0, i)),
-            pl.BlockSpec((B, 1), lambda i: (0, 0)),
-            pl.BlockSpec((B, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, tile_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(grads,
-      norms.astype(jnp.float32).reshape(B, 1),
-      mask.astype(jnp.float32).reshape(B, 1),
-      jnp.asarray(clip_norm, jnp.float32).reshape(1, 1),
-      _opaque_count(B))
+    )(grads, *_scalars(norms, mask, clip_norm, B))
     return out[0, :D]
 
 
-def _kernel_acc(acc_ref, g_ref, norm_ref, mask_ref, c_ref, n_ref, out_ref):
+def _kernel_acc(acc_ref, g_ref, norm_ref, mask_ref, c_ref, n_ref, out_ref,
+                w_ref):
     # same clip+reduce as _kernel, with the running accumulator tile added —
-    # out aliases acc, so this is an in-place += on the flat buffer
-    g = g_ref[...].astype(jnp.float32)   # (m, TILE_D)
-    norms = norm_ref[...]                # (m, 1)
-    mask = mask_ref[...]                 # (m, 1)
-    c = c_ref[0, 0]
-    coef = mask * jnp.minimum(1.0, c / jnp.maximum(norms, 1e-12))
-    # folding FROM the carry (not carry + tile-sum) is what makes the total
+    # out aliases acc, so this is an in-place += on the flat buffer.
+    # Folding FROM the carry (not carry + tile-sum) is what makes the total
     # identical for every tile size m: the full scan is one long fold
-    out_ref[...] = _fold_rows(g * coef, acc_ref[...], n_ref[0, 0])
+    _clip_rows(g_ref, norm_ref, mask_ref, c_ref, w_ref)
+    out_ref[...] = _fold_rows(w_ref, acc_ref[...], n_ref[0])
 
 
 def pick_tile_d(total: int, tile_d: int = TILE_D) -> int:
@@ -122,8 +136,8 @@ def pick_tile_d(total: int, tile_d: int = TILE_D) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_d"))
-def clip_accum_inplace(acc, grads, norms, mask, clip_norm, *, interpret=True,
-                       tile_d=None):
+def clip_accum_inplace(acc, grads, norms, mask, clip_norm, *,
+                       interpret: bool, tile_d=None):
     """acc (D,) f32 += Σ_b mask·min(1, C/norm)·grads[b]; acc is aliased.
 
     ``grads`` is an (m, D) tile in its storage dtype; ``D`` must be a
@@ -145,25 +159,15 @@ def clip_accum_inplace(acc, grads, norms, mask, clip_norm, *, interpret=True,
             f"flat length {D} must divide the kernel tile {tile_d} "
             f"(FlatGradView totals are 256-aligned; pass tile_d=... for "
             f"other layouts)")
+    in_specs, scratch = _specs(m, tile_d)
     out = pl.pallas_call(
         _kernel_acc,
         grid=(D // tile_d,),
-        in_specs=[
-            pl.BlockSpec((1, tile_d), lambda i: (0, i)),
-            pl.BlockSpec((m, tile_d), lambda i: (0, i)),
-            pl.BlockSpec((m, 1), lambda i: (0, 0)),
-            pl.BlockSpec((m, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, tile_d), lambda i: (0, i))] + in_specs,
         out_specs=pl.BlockSpec((1, tile_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
+        scratch_shapes=scratch,
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(acc.reshape(1, D),
-      grads,
-      norms.astype(jnp.float32).reshape(m, 1),
-      mask.astype(jnp.float32).reshape(m, 1),
-      jnp.asarray(clip_norm, jnp.float32).reshape(1, 1),
-      _opaque_count(m))
+    )(acc.reshape(1, D), grads, *_scalars(norms, mask, clip_norm, m))
     return out[0]

@@ -25,7 +25,9 @@ TILE_T = 128
 
 
 def _kernel(x_ref, dy_ref, out_ref, *, tt: int):
-    # x (1, T, TILE_I), dy (1, T, TILE_O) -> scalar partial into out (1, 1)
+    # x (1, T, TILE_I), dy (1, T, TILE_O) -> scalar partial, broadcast over
+    # example b's whole (1, 8, 128) output block (one f32 vreg: the smallest
+    # block the TPU tiling accepts; the caller reads element [b, 0, 0])
     T = x_ref.shape[1]
     nt = T // tt
 
@@ -49,7 +51,8 @@ def _kernel(x_ref, dy_ref, out_ref, *, tt: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tiles"))
-def ghost_norm_dense(x, dy, *, interpret=True, tiles=(TILE_I, TILE_O, TILE_T)):
+def ghost_norm_dense(x, dy, *, interpret: bool,
+                     tiles=(TILE_I, TILE_O, TILE_T)):
     """x (B, T, din), dy (B, T, dout) -> (B,) per-example ‖XᵀdY‖²_F."""
     ti, to, tt = tiles
     B, T, di = x.shape
@@ -75,8 +78,8 @@ def ghost_norm_dense(x, dy, *, interpret=True, tiles=(TILE_I, TILE_O, TILE_T)):
             pl.BlockSpec((1, Tp, ti), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((1, Tp, to), lambda b, i, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, i, j: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 8, 128), lambda b, i, j: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 8, 128), jnp.float32),
         interpret=interpret,
     )(x, dy)
-    return out[:, 0]
+    return out[:, 0, 0]

@@ -13,8 +13,8 @@ Two noise sources:
     runs everywhere, including interpret mode on CPU;
   * in-kernel generation (``seed=`` instead of ``noise``): the noise never
     touches HBM.  On TPU each program seeds the hardware PRNG with
-    (seed, program_id) and draws uniform bits in VMEM; everywhere else
-    (interpret mode, CPU) the kernel runs a counter-based Threefry-2x32
+    (seed, program_id) and draws uniform bits in VMEM; in interpret mode
+    (CPU) the kernel runs a counter-based Threefry-2x32
     written in plain jnp (:func:`threefry2x32`) over the block's global
     element indices — same contract, fully lowerable, and deterministic in
     the counter so the draw is reproducible outside the kernel for parity
@@ -27,13 +27,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:                                     # TPU-only module; absent lowerings
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                      # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 TILE = 4096
+LANES = 128
 
 _TWO_PI = 6.283185307179586
 
@@ -43,10 +40,14 @@ def bits_to_normal(b1, b2):
 
     u1 is mapped into (0, 1] (24 high bits, offset by 2^-25) so log(u1) is
     finite; u2 into [0, 1).  Pure jnp — shared by the in-kernel RNG path and
-    the CPU unit tests.
+    the CPU unit tests.  The 24-bit values go to f32 through int32: exact,
+    and a cast Mosaic lowers (it has no uint32 -> f32 conversion).
     """
-    u1 = (b1 >> 8).astype(jnp.float32) * (1.0 / (1 << 24)) + (0.5 / (1 << 24))
-    u2 = (b2 >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    def unit(b):
+        return (b >> 8).astype(jnp.int32).astype(jnp.float32) \
+            * (1.0 / (1 << 24))
+    u1 = unit(b1) + (0.5 / (1 << 24))
+    u2 = unit(b2)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos(_TWO_PI * u2)
 
@@ -106,13 +107,19 @@ def _kernel_mom_plain(p_ref, a_ref, m_ref, s_ref, newp_ref, newm_ref):
     newp_ref[...] = p_ref[...] - lr * m
 
 
+_GOLDEN = -1640531527                    # 0x9E3779B9 as int32 (odd)
+
+
 def _draw_normal(seed_ref, shape):
-    """Seed the per-core PRNG on (seed0, seed1, program_id) and draw a
-    standard-normal block.  Distinct programs (and distinct leaves, via the
-    caller folding the leaf index into the seed) get independent streams."""
-    pltpu.prng_seed(seed_ref[0], seed_ref[1], pl.program_id(0))
-    b1 = pltpu.prng_random_bits(shape).astype(jnp.uint32)
-    b2 = pltpu.prng_random_bits(shape).astype(jnp.uint32)
+    """Seed the per-core PRNG on (seed0, seed1 ^ program_id·φ) and draw a
+    standard-normal block.  Mosaic seeds from at most two words, so the
+    program id is folded into the second through an odd multiply (a
+    bijection mod 2^32): distinct programs of one leaf get distinct seeds,
+    and distinct leaves — the caller adds the leaf index to both words —
+    differ in the first."""
+    pltpu.prng_seed(seed_ref[0], seed_ref[1] ^ (pl.program_id(0) * _GOLDEN))
+    b1 = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+    b2 = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
     return bits_to_normal(b1, b2)
 
 
@@ -134,11 +141,13 @@ def _kernel_mom_rng(seed_ref, p_ref, a_ref, m_ref, s_ref, newp_ref, newm_ref):
 
 def _tf_noise(seed_ref, shape):
     """Counter-based draw: the counter is the element's GLOBAL flat index
-    (program_id * block + in-block iota), so the stream depends only on
-    (seed, index) — not on the grid/tile decomposition."""
+    (program_id * block + row-major in-block index), so the stream depends
+    only on (seed, index) — not on the grid/tile decomposition."""
     n = shape[0] * shape[1]
     base = (pl.program_id(0) * n).astype(jnp.uint32)
-    c0 = base + jax.lax.broadcasted_iota(jnp.uint32, shape, dimension=1)
+    c0 = (base
+          + jax.lax.broadcasted_iota(jnp.uint32, shape, 0) * jnp.uint32(shape[1])
+          + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
     c1 = jnp.zeros(shape, jnp.uint32)
     b1, b2 = threefry2x32(seed_ref[0, 0], seed_ref[0, 1], c0, c1)
     return bits_to_normal(b1, b2)
@@ -161,19 +170,28 @@ def _kernel_mom_rng_tf(seed_ref, p_ref, a_ref, m_ref, s_ref,
     newp_ref[...] = p_ref[...] - lr * m
 
 
+def _seed_words(seed):
+    # the hardware PRNG takes int32 seed words: reinterpret the key's bits
+    return jax.lax.bitcast_convert_type(
+        jnp.asarray(seed, jnp.uint32).reshape(2), jnp.int32)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("interpret", "tile"))
 def noisy_sgd_update(params, acc, noise, sigma_c, expected_batch, lr,
                      *, momentum_buf=None, momentum=0.0, seed=None,
-                     interpret=True, tile=TILE):
+                     interpret: bool, tile=TILE):
     """Flat f32 arrays (D,): p - lr * ((acc + sigma_c * noise)/L) [+momentum].
+
+    The kernel sees each buffer as (rows, 128) — the TPU's lane width, so a
+    ``tile``-element block fills whole vregs — padded to a tile multiple.
 
     ``noise`` is the flat N(0,1) operand; pass ``noise=None, seed=<(2,)
     uint32>`` instead to generate it in-kernel — the HBM round-trip for the
-    noise buffer disappears.  On TPU that uses the hardware PRNG; in
-    interpret mode (and anywhere ``pltpu`` is absent) it falls back to the
-    in-kernel :func:`threefry2x32` counter stream over global element
-    indices, which an outside caller can reproduce bitwise.  With
+    noise buffer disappears.  Compiled (``interpret=False``) that uses the
+    TPU hardware PRNG; in interpret mode it falls back to the in-kernel
+    :func:`threefry2x32` counter stream over global element indices, which
+    an outside caller can reproduce bitwise.  With
     ``noise=None, seed=None`` the noise term is dropped entirely (the
     non-private / sigma=0 step: no zero buffer is read).
     """
@@ -181,19 +199,19 @@ def noisy_sgd_update(params, acc, noise, sigma_c, expected_batch, lr,
     pad = (-D) % tile
     noiseless = noise is None and seed is None
     in_kernel_rng = noise is None and seed is not None
-    use_tf = in_kernel_rng and (pltpu is None or interpret)
+    use_tf = in_kernel_rng and interpret
 
     def pp(a):
-        return jnp.pad(a.astype(jnp.float32), (0, pad)).reshape(1, -1)
+        return jnp.pad(a.astype(jnp.float32), (0, pad)).reshape(-1, LANES)
 
     p, a = pp(params), pp(acc)
     Dp = D + pad
     grid = (Dp // tile,)
-    bs = pl.BlockSpec((1, tile), lambda i: (0, i))
+    bs = pl.BlockSpec((tile // LANES, LANES), lambda i: (i, 0))
     whole = lambda n: pl.BlockSpec((1, n), lambda i: (0, 0))
     if momentum_buf is None:
         s = jnp.array([[sigma_c, 1.0 / expected_batch, lr]], jnp.float32)
-        out_shape = jax.ShapeDtypeStruct((1, Dp), jnp.float32)
+        out_shape = jax.ShapeDtypeStruct(p.shape, jnp.float32)
         if noiseless:
             out = pl.pallas_call(
                 _kernel_plain, grid=grid,
@@ -212,17 +230,17 @@ def noisy_sgd_update(params, acc, noise, sigma_c, expected_batch, lr,
                 in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                           bs, bs, whole(3)],
                 out_specs=bs, out_shape=out_shape, interpret=interpret,
-            )(jnp.asarray(seed, jnp.uint32).reshape(2), p, a, s)
+            )(_seed_words(seed), p, a, s)
         else:
             out = pl.pallas_call(
                 _kernel, grid=grid,
                 in_specs=[bs, bs, bs, whole(3)],
                 out_specs=bs, out_shape=out_shape, interpret=interpret,
             )(p, a, pp(noise), s)
-        return out[0, :D]
+        return out.reshape(-1)[:D]
     m = pp(momentum_buf)
     s = jnp.array([[sigma_c, 1.0 / expected_batch, lr, momentum]], jnp.float32)
-    out_shapes = [jax.ShapeDtypeStruct((1, Dp), jnp.float32)] * 2
+    out_shapes = [jax.ShapeDtypeStruct(p.shape, jnp.float32)] * 2
     if noiseless:
         newp, newm = pl.pallas_call(
             _kernel_mom_plain, grid=grid,
@@ -241,11 +259,11 @@ def noisy_sgd_update(params, acc, noise, sigma_c, expected_batch, lr,
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                       bs, bs, bs, whole(4)],
             out_specs=[bs, bs], out_shape=out_shapes, interpret=interpret,
-        )(jnp.asarray(seed, jnp.uint32).reshape(2), p, a, m, s)
+        )(_seed_words(seed), p, a, m, s)
     else:
         newp, newm = pl.pallas_call(
             _kernel_mom, grid=grid,
             in_specs=[bs, bs, bs, bs, whole(4)],
             out_specs=[bs, bs], out_shape=out_shapes, interpret=interpret,
         )(p, a, pp(noise), m, s)
-    return newp[0, :D], newm[0, :D]
+    return newp.reshape(-1)[:D], newm.reshape(-1)[:D]

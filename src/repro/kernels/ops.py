@@ -38,15 +38,20 @@ from .ghost_norm import ghost_norm_dense  # re-export
 from .noisy_update import noisy_sgd_update
 
 __all__ = ["clip_accum", "flat_clip_accum", "ghost_norm_dense",
-           "noisy_sgd_update", "tree_clip_accum", "tree_noisy_update"]
+           "interpret_mode", "noisy_sgd_update", "tree_clip_accum",
+           "tree_noisy_update"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted: exactly when the default
+    backend is not a TPU.  The one place that decision is made — on a TPU
+    every kernel compiles through Mosaic, and off it the interpreter runs
+    the same kernel body as XLA ops."""
+    return jax.default_backend() != "tpu"
 
 
 def tree_clip_accum(per_example_grads, norms, mask, clip_norm, *,
-                    interpret=True):
+                    interpret: bool):
     """per_example_grads: pytree with leading B axis -> clipped masked sum."""
     leaves, treedef = jax.tree.flatten(per_example_grads)
     B = leaves[0].shape[0]
@@ -67,7 +72,7 @@ def tree_clip_accum(per_example_grads, norms, mask, clip_norm, *,
 
 
 def flat_clip_accum(acc, tile_grads, norms, mask, clip_norm, *,
-                    interpret=True, tile_d=None):
+                    interpret: bool, tile_d=None):
     """Streaming accumulate: ``acc (D,) += Σ_b coef_b · tile_grads[b]``.
 
     ``tile_grads`` is an (m, D) per-example tile already in the flat
@@ -87,7 +92,8 @@ def tree_noisy_update(params, grad_acc, key, sigma_c, expected_batch, lr, *,
                       view: Optional[FlatGradView] = None,
                       use_kernel: Optional[bool] = None,
                       interpret: Optional[bool] = None,
-                      in_kernel_rng: Optional[bool] = None):
+                      in_kernel_rng: Optional[bool] = None,
+                      kernel_map=None):
     """Fused DP-SGD apply: params tree + flat accumulator -> new params tree.
 
     ``grad_acc`` is the flat f32 accumulator laid out by ``view`` (built from
@@ -104,13 +110,18 @@ def tree_noisy_update(params, grad_acc, key, sigma_c, expected_batch, lr, *,
     The default (``None``) keeps the historical choice — in-kernel on real
     TPU, noise-operand everywhere else, so off-TPU callers keep sharing one
     ``view.noise`` stream with the generic path.
+
+    ``kernel_map`` (``ShardingConstraints.kernel_map``) runs each kernel
+    call on every device of a mesh over the replicated buffers: a Mosaic
+    kernel compiles under a mesh only that way.  Every device draws the
+    same in-kernel noise from the same seed, so the replicas stay equal.
     """
     if view is None:
         view = FlatGradView.for_tree(params)
     if not (hasattr(grad_acc, "ndim") and grad_acc.ndim == 1):
         grad_acc = view.flatten(grad_acc)          # legacy pytree accumulator
-    use_kernel = _on_tpu() if use_kernel is None else use_kernel
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    use_kernel = (not interpret_mode()) if use_kernel is None else use_kernel
+    interpret = interpret_mode() if interpret is None else interpret
     leaves = jax.tree.leaves(params)
 
     # static sigma*C (the usual case: DPConfig floats) is declared on the
@@ -130,30 +141,34 @@ def tree_noisy_update(params, grad_acc, key, sigma_c, expected_batch, lr, *,
             seeds = kd.astype(jnp.uint32).reshape(-1)[-2:]
         else:
             seeds = None
+        def leaf_update(p, a, z, m, seed, sc, denom, lr_):
+            return noisy_sgd_update(p, a, z, sc, denom, lr_, momentum_buf=m,
+                                    momentum=momentum, seed=seed,
+                                    interpret=interpret)
+        sc, denom, lr_ = (sigma_c if key is not None else 0.0,
+                          expected_batch, lr)
+        if kernel_map is not None:
+            leaf_update = kernel_map(leaf_update)
+            # shard_map passes arrays only
+            sc, denom, lr_ = (jnp.asarray(x, jnp.float32)
+                              for x in (sc, denom, lr_))
+
+        def seg(buf, o, n):
+            return None if buf is None else jax.lax.slice(buf, (o,), (o + n,))
+
         newp, newm_segs = [], []
         for i, p in enumerate(leaves):
             o, n = view.offsets[i], view.sizes[i]
-            a_seg = jax.lax.slice(grad_acc, (o,), (o + n,))
-            kw = dict(interpret=interpret)
-            if in_kernel_rng:
-                # fold the leaf index into the seed: leaves get independent
-                # in-kernel streams (program_id only separates tiles)
-                kw["seed"] = seeds + jnp.uint32(i)
-            # key=None leaves noise AND seed unset -> the kernel's noiseless
-            # variants (no zero buffer is materialised or read)
-            z_seg = (jax.lax.slice(z, (o,), (o + n,))
-                     if z is not None else None)
-            sc = sigma_c if key is not None else 0.0
-            if momentum_buf is None:
-                out = noisy_sgd_update(p.reshape(-1).astype(jnp.float32),
-                                       a_seg, z_seg, sc, expected_batch, lr,
-                                       **kw)
-            else:
-                m_seg = jax.lax.slice(momentum_buf, (o,), (o + n,))
-                out, newm = noisy_sgd_update(
-                    p.reshape(-1).astype(jnp.float32), a_seg, z_seg, sc,
-                    expected_batch, lr, momentum_buf=m_seg,
-                    momentum=momentum, **kw)
+            # fold the leaf index into the seed: leaves get independent
+            # in-kernel streams (program_id only separates tiles).  key=None
+            # leaves noise AND seed unset -> the kernel's noiseless variants
+            # (no zero buffer is materialised or read)
+            seed = seeds + jnp.uint32(i) if in_kernel_rng else None
+            out = leaf_update(p.reshape(-1).astype(jnp.float32),
+                              seg(grad_acc, o, n), seg(z, o, n),
+                              seg(momentum_buf, o, n), seed, sc, denom, lr_)
+            if momentum_buf is not None:
+                out, newm = out
                 newm_segs.append(newm)
             if in_kernel_rng:
                 # the draw happens inside the kernel: declare it on the

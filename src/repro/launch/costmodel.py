@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ArchConfig, InputShape
 from ..utils.params import flatten_params
+from .mesh import HBM_BYTES
 
 
 @dataclasses.dataclass
@@ -145,28 +146,51 @@ ENGINE_ATTN_MULT = {"nonprivate": 3.0, "pe": 3.0, "masked_pe": 3.0,
                     "masked_ghost": 5.0, "masked_bk": 3.0}
 
 # streaming engine: live bytes the tile sizing must keep under budget beyond
-# the per-example slab — the flat f32 accumulator plus one params-sized f32
-# live buffer (the summed-tile output the aliased kernel writes through)
+# the per-example slab and the train state — the flat f32 accumulator carry
+# plus one params-sized f32 live buffer (the summed-tile output the aliased
+# kernel writes through)
 STREAM_FIXED_F32_BUFFERS = 2
+# copies of one example's params-sized gradient row live at the peak: the
+# vmapped gradient tree, its flat concatenation, the tile padded to the
+# accumulator layout and the layer-stacked backward's output.  Read off
+# memory_analysis() of the full-width vit-base accumulate compiled for TPU
+# v5e: temp grew 1.27-1.39 GB per tile row at m <= 4, i.e. 3.7-4.0 rows of
+# 0.34 GB each (fewer at m = 16, so 4 is the safe side)
+STREAM_ROW_COPIES = 4
+# rows of one TPU vreg tile: the second-minor dim of an array pads to this
+SUBLANES = 8
 
 
 def stream_tile_size(batch_size: int, n_params: int,
-                     budget_bytes: float = 16 * 2 ** 30,
-                     pe_dtype_bytes: int = 4) -> int:
+                     budget_bytes: float = HBM_BYTES,
+                     pe_dtype_bytes: int = 4,
+                     state_bytes: float = 0.0) -> int:
     """Largest streaming tile m ≤ batch whose live state fits the budget.
 
     Peak live memory of the scanned clip-and-accumulate is
-    ``m · n_params · pe_dtype_bytes`` (the tile's vmapped per-example grads)
-    plus :data:`STREAM_FIXED_F32_BUFFERS` params-sized f32 buffers — the
-    O(m·params + params) the streaming engine exists for.  Pure arithmetic
+    ``STREAM_ROW_COPIES · m · n_params · pe_dtype_bytes`` (the tile's
+    per-example grads and their copies) plus
+    :data:`STREAM_FIXED_F32_BUFFERS` params-sized f32 buffers — the
+    O(m·params + params) the streaming engine exists for — plus
+    ``state_bytes``, the train state held live around the step (the caller
+    counts it: params, optimizer state and accumulator, in and out).
+
+    A tile cut below the batch is rounded down to a multiple of
+    :data:`SUBLANES`: the TPU lays an (m, D) array out in 8-row tiles, so
+    m = 10 holds as much as m = 16 (the v5e compile at vit-base widths put
+    19.7 GB of temp at m = 10 against 7.3 GB at m = 8).  Pure arithmetic
     (no jax), so sessions can size tiles at config time and dry-runs can
     price meshes far larger than the host."""
-    fixed = STREAM_FIXED_F32_BUFFERS * 4.0 * n_params
+    fixed = state_bytes + STREAM_FIXED_F32_BUFFERS * 4.0 * n_params
     free = budget_bytes - fixed
     if free <= 0:
         return 1
-    m = int(free // max(n_params * pe_dtype_bytes, 1))
-    return max(1, min(int(batch_size), m))
+    m = int(free // max(STREAM_ROW_COPIES * n_params * pe_dtype_bytes, 1))
+    if m >= batch_size:
+        return int(batch_size)
+    if m >= SUBLANES:
+        m -= m % SUBLANES
+    return max(1, m)
 
 
 def train_costs(model, cfg: ArchConfig, shape: InputShape, engine: str,

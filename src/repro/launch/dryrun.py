@@ -247,10 +247,7 @@ def lower_one(arch: str, shape_name: str, *, mesh: str = "production",
                              + ma.output_size_in_bytes
                              - ma.alias_size_in_bytes),
     }
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # jax<0.5: one dict per partition
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = compiled.cost_analysis() or {}
     rec["hlo_cost"] = {"flops": ca.get("flops", -1.0),
                        "bytes_accessed": ca.get("bytes accessed", -1.0),
                        "transcendentals": ca.get("transcendentals", -1.0)}

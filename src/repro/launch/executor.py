@@ -296,7 +296,8 @@ class MeshExecutor(Executor):
             # parity was never on offer (params themselves reshard), so the
             # memory win is taken there.
             return ShardingConstraints(pe_dtype=pe_dtype,
-                                       tile_batch=self._tile_constraint())
+                                       tile_batch=self._tile_constraint(),
+                                       kernel_map=self._kernel_map)
         return ShardingConstraints(
             grad=grads_constraint(self.mesh),
             grad_flat=flat_grads_constraint(self.mesh),
@@ -317,6 +318,26 @@ class MeshExecutor(Executor):
                     x, NamedSharding(self.mesh, self.batch_spec(x.shape[0])))
             return jax.tree.map(one, tree)
         return apply
+
+    def _kernel_map(self, fn: Callable, rows: int = 0) -> Callable:
+        """The ``ShardingConstraints.kernel_map`` of the replicated-state
+        layouts: ``fn`` runs under ``shard_map`` on each device, the first
+        ``rows`` arguments split like a batch of their leading size (or
+        replicated when that does not divide the data axes), and per-device
+        results summed over the axes the rows were split on."""
+        def call(*args):
+            spec = (self.batch_spec(args[0].shape[0]) if rows else P())
+            axes = spec[0] if len(spec) else ()
+            axes = (axes,) if isinstance(axes, str) else tuple(axes or ())
+
+            def body(*a):
+                out = fn(*a)
+                return jax.lax.psum(out, axes) if axes else out
+            in_specs = tuple(spec if i < rows else P()
+                             for i in range(len(args)))
+            return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False)(*args)
+        return call
 
     def batch_spec(self, bsz: int) -> P:
         if self.layout in ("dp", "dp_sp"):

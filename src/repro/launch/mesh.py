@@ -8,19 +8,10 @@ from __future__ import annotations
 import jax
 
 
-def _mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; Auto is the default there,
-    # so older jax just omits the kwarg.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_mesh(shape, axes):
-    """Public mesh factory (jax<0.5 AxisType compat applied)."""
-    return _mesh(shape, axes)
+    """Public mesh factory: every axis Auto-sharded (GSPMD propagation)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # Production geometry — the single source the executor's mesh presets and
@@ -31,12 +22,12 @@ MULTIPOD_SHAPE = ((2, 16, 16), ("pod", "data", "model"))
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape, axes = MULTIPOD_SHAPE if multi_pod else POD_SHAPE
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants used by the roofline (per chip).

@@ -31,6 +31,7 @@ import numpy as np
 from ..core import DPConfig
 from ..core.session import PrivacySession, TrainConfig
 from ..obs import add_cli_args, config_from_args, start_profile, stop_profile
+from .compile_cache import enable_compile_cache
 from .executor import LaunchConfig
 
 
@@ -161,6 +162,7 @@ def main():
                          "default: local")
     add_cli_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     trace_shape = args.trace_shape
     if args.profile is not None:
         warnings.warn("--profile is deprecated (reserved for profiler "

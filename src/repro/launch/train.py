@@ -21,6 +21,7 @@ from ..core.session import PrivacySession, TrainConfig
 from ..data import available_samplers
 from ..data.synthetic import dataset_for_config
 from ..obs import add_cli_args, config_from_args, start_profile, stop_profile
+from .compile_cache import enable_compile_cache
 from .executor import LaunchConfig
 
 
@@ -119,6 +120,7 @@ def main():
     ap.add_argument("--ckpt")
     add_cli_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     out = train(args.arch, smoke=args.smoke, steps=args.steps,
                 n_data=args.n_data, seq_len=args.seq_len,
                 physical=args.physical, q=args.q, sampler=args.sampler,

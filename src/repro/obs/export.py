@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import json
 import os
-import warnings
 from typing import List, Optional
 
 from .metrics import SCHEMA_VERSION
@@ -82,24 +81,15 @@ def read_jsonl(path: str, kind: Optional[str] = None) -> List[dict]:
     return body
 
 
-def start_profile(profile_dir: str) -> bool:
+def start_profile(profile_dir: str) -> None:
     """Start a jax.profiler trace into ``profile_dir`` (TensorBoard /
-    perfetto format).  Returns False (with a warning) when the backend
-    cannot trace rather than failing the run."""
-    try:
-        import jax
-        jax.profiler.start_trace(profile_dir)
-        return True
-    except Exception as e:                                  # pragma: no cover
-        warnings.warn(f"jax.profiler trace unavailable: {e}", RuntimeWarning,
-                      stacklevel=2)
-        return False
+    perfetto format).  A trace that cannot start raises: a run asked for a
+    profile must not exit 0 without one."""
+    import jax
+    jax.profiler.start_trace(profile_dir)
 
 
 def stop_profile() -> None:
-    try:
-        import jax
-        jax.profiler.stop_trace()
-    except Exception as e:                                  # pragma: no cover
-        warnings.warn(f"jax.profiler stop_trace failed: {e}", RuntimeWarning,
-                      stacklevel=2)
+    """Stop the live trace and write it out; raises when that fails."""
+    import jax
+    jax.profiler.stop_trace()

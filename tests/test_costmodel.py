@@ -36,10 +36,7 @@ def _hlo_flops(model, cfg, shape, engine):
                     (shape.global_batch, shape.seq_len), jnp.int32)}
         mask = jax.ShapeDtypeStruct((shape.global_batch,), jnp.float32)
         c = jax.jit(step).lower(state_shape, batch, mask).compile()
-        ca = c.cost_analysis()
-        if isinstance(ca, list):        # jax<0.5: one dict per partition
-            ca = ca[0] if ca else {}
-        return (ca or {}).get("flops", 0.0)
+        return (c.cost_analysis() or {}).get("flops", 0.0)
     finally:
         set_scan_unroll(1)
 
@@ -92,3 +89,20 @@ def test_decode_costs_scale_with_cache():
     assert s2.hbm_bytes > s1.hbm_bytes
     assert s2.detail["cache_bytes"] == pytest.approx(
         4 * s1.detail["cache_bytes"])
+
+
+@pytest.mark.parametrize("rows,state_rows,want", [
+    (40, 0, 32),    # the whole batch fits
+    (13, 0, 8),     # cut below the batch: a multiple of the 8-row tiling
+    (5, 0, 5),      # fewer than 8 rows fit: as many as fit
+    (13, 6, 7),     # the live train state comes off the budget first
+    (0, 0, 1),      # nothing left: one row
+])
+def test_stream_tile_size_budget(rows, state_rows, want):
+    """The streaming tile is the largest m whose per-example rows fit the
+    budget net of the fixed f32 buffers and the live train state."""
+    n = 1_000_000
+    row = costmodel.STREAM_ROW_COPIES * 4 * n
+    budget = costmodel.STREAM_FIXED_F32_BUFFERS * 4 * n + rows * row
+    assert costmodel.stream_tile_size(32, n, budget_bytes=budget,
+                                      state_bytes=state_rows * row) == want

@@ -230,7 +230,7 @@ print(json.dumps({"n": len(out["generated"]),
 
 
 LOWER_KEYS = {"arch", "shape", "kind", "mesh", "engine", "microbatches",
-              "unrolled", "lower_s"}
+              "sampler", "unrolled", "lower_s"}
 COMPILE_KEYS = LOWER_KEYS | {"compile_s", "memory", "hlo_cost", "collectives",
                              "analytic", "roofline", "fits_hbm"}
 MEMORY_KEYS = {"argument_bytes", "output_bytes", "temp_bytes", "alias_bytes",

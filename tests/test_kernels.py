@@ -19,7 +19,7 @@ def test_clip_accum_sweep(B, D, dtype):
     norms = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (B,))) * 2
     mask = (jax.random.uniform(jax.random.PRNGKey(2), (B,)) > 0.3).astype(
         jnp.float32)
-    out = clip_accum(g, norms, mask, 0.7, tile_d=256)
+    out = clip_accum(g, norms, mask, 0.7, interpret=True, tile_d=256)
     expect = ref.clip_accum_ref(g, norms, mask, 0.7)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=1e-5)
@@ -31,7 +31,7 @@ def test_clip_accum_sweep(B, D, dtype):
 def test_ghost_norm_sweep(B, T, di, do, dtype):
     x = jax.random.normal(jax.random.PRNGKey(0), (B, T, di), dtype)
     dy = jax.random.normal(jax.random.PRNGKey(1), (B, T, do), dtype) * 0.1
-    out = ghost_norm_dense(x, dy, tiles=(32, 32, 16))
+    out = ghost_norm_dense(x, dy, interpret=True, tiles=(32, 32, 16))
     expect = ref.ghost_norm_dense_ref(x, dy)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=5e-3 if dtype == jnp.bfloat16 else 1e-4)
@@ -48,12 +48,13 @@ def test_noisy_update_sweep(D, momentum):
         m = jax.random.normal(ks[3], (D,))
         newp, newm = noisy_sgd_update(p, a, z, 1.5, 64.0, 0.01,
                                       momentum_buf=m, momentum=momentum,
-                                      tile=512)
+                                      interpret=True, tile=512)
         rp, rm = ref.noisy_sgd_update_ref(p, a, z, 1.5, 64.0, 0.01, m, momentum)
         np.testing.assert_allclose(np.asarray(newm), np.asarray(rm),
                                    rtol=1e-5, atol=1e-6)
     else:
-        newp = noisy_sgd_update(p, a, z, 1.5, 64.0, 0.01, tile=512)
+        newp = noisy_sgd_update(p, a, z, 1.5, 64.0, 0.01, interpret=True,
+                                tile=512)
         rp = ref.noisy_sgd_update_ref(p, a, z, 1.5, 64.0, 0.01)
     np.testing.assert_allclose(np.asarray(newp), np.asarray(rp),
                                rtol=1e-5, atol=1e-6)
@@ -67,7 +68,7 @@ def test_tree_wrappers_match_engine():
     sq = sum(jnp.sum(g.reshape(B, -1) ** 2, -1) for g in jax.tree.leaves(grads))
     norms = jnp.sqrt(sq)
     mask = jnp.array([1., 0., 1., 1., 0.])
-    out = tree_clip_accum(grads, norms, mask, 0.3)
+    out = tree_clip_accum(grads, norms, mask, 0.3, interpret=True)
 
     from repro.core.clipping import clip_coef
     coef, _ = clip_coef(sq, mask, 0.3)
@@ -140,7 +141,8 @@ def test_clip_accum_inplace_matches_ref(m):
     order is the whole point, allclose would not pin it."""
     from repro.kernels.clip_accum import clip_accum_inplace
     acc, g, norms, mask = _inplace_case(m, 512)
-    out = clip_accum_inplace(acc, g, norms, mask, 0.7, tile_d=256)
+    out = clip_accum_inplace(acc, g, norms, mask, 0.7, interpret=True,
+                             tile_d=256)
     expect = ref.clip_accum_inplace_ref(acc, g, norms, mask, 0.7)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
 
@@ -153,15 +155,15 @@ def test_clip_accum_inplace_tile_invariance():
     break this)."""
     from repro.kernels.clip_accum import clip_accum_inplace
     acc, g, norms, mask = _inplace_case(4, 256, seed=3)
-    whole = clip_accum_inplace(acc, g, norms, mask, 0.5)
+    whole = clip_accum_inplace(acc, g, norms, mask, 0.5, interpret=True)
     two = acc
     for i in (0, 2):
         two = clip_accum_inplace(two, g[i:i + 2], norms[i:i + 2],
-                                 mask[i:i + 2], 0.5)
+                                 mask[i:i + 2], 0.5, interpret=True)
     ones = acc
     for i in range(4):
         ones = clip_accum_inplace(ones, g[i:i + 1], norms[i:i + 1],
-                                  mask[i:i + 1], 0.5)
+                                  mask[i:i + 1], 0.5, interpret=True)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(two))
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(ones))
 
@@ -177,7 +179,7 @@ def test_clip_accum_inplace_padded_tail_stays_zero():
     for seed in (0, 1):
         _, g, norms, mask = _inplace_case(3, D, seed=seed)
         g = g.at[:, n_params:].set(0.0)
-        acc = clip_accum_inplace(acc, g, norms, mask, 0.9)
+        acc = clip_accum_inplace(acc, g, norms, mask, 0.9, interpret=True)
     out = np.asarray(acc)
     assert np.all(out[n_params:] == 0.0)
     assert np.any(out[:n_params] != 0.0)
@@ -187,9 +189,10 @@ def test_clip_accum_inplace_shape_errors():
     from repro.kernels.clip_accum import clip_accum_inplace
     acc, g, norms, mask = _inplace_case(2, 300)
     with pytest.raises(ValueError, match="must divide"):
-        clip_accum_inplace(acc, g, norms, mask, 1.0, tile_d=256)
+        clip_accum_inplace(acc, g, norms, mask, 1.0, interpret=True,
+                           tile_d=256)
     with pytest.raises(ValueError, match="acc shape"):
-        clip_accum_inplace(acc[:256], g, norms, mask, 1.0)
+        clip_accum_inplace(acc[:256], g, norms, mask, 1.0, interpret=True)
 
 
 def _tf_stream(seed, total):
@@ -219,8 +222,9 @@ def test_noisy_update_in_kernel_threefry_parity(momentum):
         kw = dict(momentum_buf=jax.random.normal(ks[2], (D,)),
                   momentum=momentum)
     got = noisy_sgd_update(p, a, None, 1.5, 64.0, 0.01, seed=seed,
-                           tile=tile, **kw)
-    want = noisy_sgd_update(p, a, z, 1.5, 64.0, 0.01, tile=tile, **kw)
+                           interpret=True, tile=tile, **kw)
+    want = noisy_sgd_update(p, a, z, 1.5, 64.0, 0.01, interpret=True,
+                            tile=tile, **kw)
     got = got if momentum else (got,)
     want = want if momentum else (want,)
     for gw, ww in zip(got, want):
@@ -249,7 +253,7 @@ def test_tree_noisy_update_in_kernel_rng_reproducible():
         z = _tf_stream(kd + jnp.uint32(i), n + (-n) % TILE)[:n]
         zs.append(np.asarray(z))
         expect = noisy_sgd_update(p.reshape(-1), acc[o:o + n], z,
-                                  1.3, 16.0, 0.05)
+                                  1.3, 16.0, 0.05, interpret=True)
         np.testing.assert_array_equal(np.asarray(got).reshape(-1),
                                       np.asarray(expect))
     assert not np.array_equal(zs[0][:33], zs[1])

@@ -63,11 +63,9 @@ state_shape = jax.eval_shape(lambda: init_state(model.init(jax.random.PRNGKey(0)
 specs = input_specs(cfg, InputShape("t", 16, 8, "train"))
 c = ex.lower_train(step, state_shape, specs["batch"], specs["mask"]).compile()
 ma = c.memory_analysis()
-ca = c.cost_analysis()
-if isinstance(ca, list):        # jax<0.5: one dict per partition
-    ca = ca[0] if ca else {}
+ca = c.cost_analysis() or {}
 print(json.dumps({"ok": True, "temp": ma.temp_size_in_bytes,
-                  "flops": (ca or {}).get("flops", -1)}))
+                  "flops": ca.get("flops", -1)}))
 """)
     rec = json.loads(out.strip().splitlines()[-1])
     assert rec["ok"]

@@ -330,6 +330,23 @@ def test_obs_cli_flags_roundtrip(tmp_path):
     assert reg2.mode == "events" and reg2.annotate
 
 
+@pytest.mark.parametrize("which", ["start", "stop"])
+def test_profile_failure_raises(which, monkeypatch, tmp_path):
+    """A run asked for --profile-dir must not exit 0 without a trace: a
+    profiler that fails to start or stop raises instead of warning."""
+    from repro.obs import start_profile, stop_profile
+
+    def broken(*a, **k):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, f"{which}_trace", broken)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        if which == "start":
+            start_profile(str(tmp_path / "prof"))
+        else:
+            stop_profile()
+
+
 def test_serve_cli_profile_renamed_to_trace_shape(monkeypatch, capsys):
     from repro.launch import serve as serve_cli
     seen = {}
@@ -339,6 +356,8 @@ def test_serve_cli_profile_renamed_to_trace_shape(monkeypatch, capsys):
         return {"ok": True}
 
     monkeypatch.setattr(serve_cli, "replay", fake_replay)
+    # the CLI turns on the persistent compile cache; a test leaves it off
+    monkeypatch.setattr(serve_cli, "enable_compile_cache", lambda: None)
     monkeypatch.setattr(sys, "argv",
                         ["serve", "--requests", "2", "--profile", "bimodal"])
     with pytest.warns(DeprecationWarning, match="--trace-shape"):
