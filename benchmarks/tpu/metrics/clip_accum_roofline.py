@@ -1,0 +1,11 @@
+"""clip_accum's share of its roofline: the least time its calls in the window
+could take on this chip (operations over peak FLOP/s or bytes over peak
+bandwidth, whichever is larger, from counts/clip_accum.py and peaks.json) over
+the device time they took, in percent."""
+
+
+def read(red, counters, cell):
+    k = red["kernels"].get("clip_accum")
+    if not k or k["s"] <= 0:
+        return None
+    return 100.0 * k["least_s"] / k["s"]
