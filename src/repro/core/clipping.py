@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.marks import mark as dp_mark
+from ..obs.phases import phase
 from ..utils.params import grads_into_tree, missing_paths
 from . import layers
 from .tape import Tape
@@ -168,8 +169,9 @@ def clip_coef(sq_norms, mask, clip_norm):
     tape recombination) inherits the ``clipped`` taint from this one value,
     so the static verifier (:mod:`repro.analysis`) accepts an aggregation
     only if this coefficient participates in it."""
-    norms = jnp.sqrt(jnp.maximum(sq_norms, 1e-24))
-    coef = dp_mark("clip", mask * jnp.minimum(1.0, clip_norm / norms))
+    with phase("grad/accumulate"):
+        norms = jnp.sqrt(jnp.maximum(sq_norms, 1e-24))
+        coef = dp_mark("clip", mask * jnp.minimum(1.0, clip_norm / norms))
     return coef, norms
 
 
@@ -188,13 +190,14 @@ def per_example_grads_and_sq(loss_fn: Callable, params, batch,
         ex1 = jax.tree.map(lambda x: x[None], ex)
         return loss_fn(p, ex1, Tape())[0]
 
-    grads = jax.vmap(jax.grad(one_loss), in_axes=(None, 0))(params, batch)
-    if pe_dtype is not None:
-        grads = jax.tree.map(lambda g: g.astype(pe_dtype), grads)
-    if pe_constraint is not None:
-        grads = pe_constraint(grads)
-    sq = sum(jnp.sum(g.reshape(g.shape[0], -1).astype(jnp.float32) ** 2, -1)
-             for g in jax.tree.leaves(grads))
+    with phase("grad/per_example"):
+        grads = jax.vmap(jax.grad(one_loss), in_axes=(None, 0))(params, batch)
+        if pe_dtype is not None:
+            grads = jax.tree.map(lambda g: g.astype(pe_dtype), grads)
+        if pe_constraint is not None:
+            grads = pe_constraint(grads)
+        sq = sum(jnp.sum(g.reshape(g.shape[0], -1).astype(jnp.float32) ** 2, -1)
+                 for g in jax.tree.leaves(grads))
     return grads, sq
 
 
@@ -218,7 +221,8 @@ def per_example_clipped_grads(loss_fn: Callable, params, batch, mask,
         return jax.lax.scan(lambda a, r: (a + r, None),
                             jnp.zeros(w.shape[1:], jnp.float32), w)[0]
 
-    summed = jax.tree.map(wsum, grads)
+    with phase("grad/accumulate"):
+        summed = jax.tree.map(wsum, grads)
     return summed, {"per_example_norms": norms, "clip_coef": coef}
 
 
@@ -227,9 +231,10 @@ def per_example_grad_norms(loss_fn, params, batch) -> jnp.ndarray:
     def one_loss(p, ex):
         ex1 = jax.tree.map(lambda x: x[None], ex)
         return loss_fn(p, ex1, Tape())[0]
-    grads = jax.vmap(jax.grad(one_loss), in_axes=(None, 0))(params, batch)
-    sq = sum(jnp.sum(g.reshape(g.shape[0], -1).astype(jnp.float32) ** 2, -1)
-             for g in jax.tree.leaves(grads))
+    with phase("grad/per_example"):
+        grads = jax.vmap(jax.grad(one_loss), in_axes=(None, 0))(params, batch)
+        sq = sum(jnp.sum(g.reshape(g.shape[0], -1).astype(jnp.float32) ** 2, -1)
+                 for g in jax.tree.leaves(grads))
     return jnp.sqrt(sq)
 
 
@@ -269,11 +274,12 @@ def _eps_backward(loss_fn, params, batch):
 
 def ghost_norms(loss_fn, params, batch):
     """Per-example grad sq-norms via the ghost trick (no per-example grads)."""
-    dEps, records, specs, losses = _eps_backward(loss_fn, params, batch)
-    sq = jnp.zeros(losses.shape[0], jnp.float32)
-    for name, spec in specs.items():
-        rec = layers.resolve_record(records, name, spec)
-        sq = sq + layers.per_example_sq_norm(spec, rec, dEps[name])
+    with phase("grad/norm_pass"):
+        dEps, records, specs, losses = _eps_backward(loss_fn, params, batch)
+        sq = jnp.zeros(losses.shape[0], jnp.float32)
+        for name, spec in specs.items():
+            rec = layers.resolve_record(records, name, spec)
+            sq = sq + layers.per_example_sq_norm(spec, rec, dEps[name])
     return sq, losses
 
 
@@ -291,8 +297,10 @@ def ghost_clipped_grads(loss_fn: Callable, params, batch, mask,
         losses = loss_fn(p, batch, Tape())
         return jnp.sum(coef * losses)
 
-    summed = jax.grad(reweighted)(params)
-    summed = jax.tree.map(lambda g: g.astype(jnp.float32), summed)
+    with phase("grad/reweighted"):
+        summed = jax.grad(reweighted)(params)
+    with phase("grad/accumulate"):
+        summed = jax.tree.map(lambda g: g.astype(jnp.float32), summed)
     return summed, {"per_example_norms": norms, "clip_coef": coef}
 
 

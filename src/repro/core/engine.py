@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..analysis.marks import mark as dp_mark, mark_tree as dp_mark_tree
 from ..kernels import tree_noisy_update
+from ..obs.phases import phase
 from ..optim import Optimizer
 from ..utils.params import FlatGradView
 from . import clipping
@@ -214,14 +215,17 @@ def build_accumulate_fn(loss_fn: Callable, cfg: DPConfig, *,
             def sum_loss(p):
                 losses = loss_fn(p, batch, Tape())
                 return (losses * mask).sum()
-            g = jax.grad(sum_loss)(state.params)
-            g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
+            with phase("grad/batch"):
+                g = jax.grad(sum_loss)(state.params)
+            with phase("grad/accumulate"):
+                g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
             metrics = {}
         if grad_constraint is not None:
             g = grad_constraint(g)
         # ONE scatter of the clipped sum into the flat accumulator (the
         # concat fuses with the producers — no per-leaf buffer round-trip)
-        acc = state.grad_acc + view.flatten(g)
+        with phase("grad/accumulate"):
+            acc = state.grad_acc + view.flatten(g)
         if constraints is not None and constraints.grad_flat is not None:
             acc = constraints.grad_flat(acc)
         return state._replace(grad_acc=acc, seen=state.seen + mask.sum()), metrics

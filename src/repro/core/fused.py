@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import flat_clip_accum, interpret_mode, tree_clip_accum
+from ..obs.phases import phase
 from ..utils.params import FlatGradView
 from .clipping import (Aux, ShardingConstraints, clip_coef, ghost_norms,
                        per_example_grads_and_sq, register_engine)
@@ -176,26 +177,27 @@ def streaming_clipped_grads(loss_fn: Callable, params, batch, mask,
         grads, sq = per_example_grads_and_sq(loss_fn, params, b, constraints)
         if not ghost:
             coef, norms = clip_coef(sq, mk, clip_norm)
-        leaves = jax.tree.leaves(grads)
-        tile_flat = (jnp.concatenate([l.reshape(m_eff, -1) for l in leaves],
-                                     axis=1)
-                     if len(leaves) > 1 else leaves[0].reshape(m_eff, -1))
-        if pad_d:
-            # pad the m-row tile (O(m·params)) to the accumulator layout;
-            # the accumulator itself is NEVER padded/copied here — that
-            # would break the kernel's input/output aliasing
-            tile_flat = jnp.pad(tile_flat, ((0, 0), (0, pad_d)))
-        if kernel_map is None:
-            carry = flat_clip_accum(carry, tile_flat, norms, mk, clip_norm,
-                                    interpret=interpret)
-        else:
-            # on a mesh each device folds its rows of the tile from zero
-            # and the partial sums are all-reduced into the carry
-            carry = carry + kernel_map(
-                lambda g, n, k: flat_clip_accum(
-                    jnp.zeros((g.shape[1],), jnp.float32), g, n, k,
-                    clip_norm, interpret=interpret), rows=3)(
-                        tile_flat, norms, mk)
+        with phase("grad/accumulate"):
+            leaves = jax.tree.leaves(grads)
+            tile_flat = (jnp.concatenate([l.reshape(m_eff, -1) for l in leaves],
+                                         axis=1)
+                         if len(leaves) > 1 else leaves[0].reshape(m_eff, -1))
+            if pad_d:
+                # pad the m-row tile (O(m·params)) to the accumulator layout;
+                # the accumulator itself is NEVER padded/copied here — that
+                # would break the kernel's input/output aliasing
+                tile_flat = jnp.pad(tile_flat, ((0, 0), (0, pad_d)))
+            if kernel_map is None:
+                carry = flat_clip_accum(carry, tile_flat, norms, mk, clip_norm,
+                                        interpret=interpret)
+            else:
+                # on a mesh each device folds its rows of the tile from zero
+                # and the partial sums are all-reduced into the carry
+                carry = carry + kernel_map(
+                    lambda g, n, k: flat_clip_accum(
+                        jnp.zeros((g.shape[1],), jnp.float32), g, n, k,
+                        clip_norm, interpret=interpret), rows=3)(
+                            tile_flat, norms, mk)
         # aux reports the tile's m real examples (drop the vmap-width pad)
         return carry, (norms[:m], coef[:m])
 

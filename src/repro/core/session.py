@@ -470,7 +470,7 @@ class PrivacySession:
         # the memory manager places each physical batch through the executor
         # as it is produced (host->device/mesh transfer off the step path)
         bmm = BatchMemoryManager(dataset.fetch, tc.physical_batch,
-                                 place=self.executor.place)
+                                 place=self.executor.place, obs=self.obs)
 
         history = []
         obs = self.obs
@@ -480,10 +480,13 @@ class PrivacySession:
         # `start` (no device sync on the step path)
         init_step = start
         last_async_at = done = 0
+        draws = iter(sampler)
         try:
-            for step_i, indices in enumerate(sampler):
+            for step_i in range(steps):
                 obs.tick()
                 with obs.span("fit/accumulate") as sp:
+                    with obs.span("fit/sample"):
+                        indices = next(draws)
                     acc_metrics = None
                     for pb in bmm.batches(indices):
                         # pb is already placed by the memory manager's

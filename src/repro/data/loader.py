@@ -13,6 +13,8 @@ from typing import Callable, Iterator, Optional, Tuple
 
 import numpy as np
 
+from ..obs import NULL_REGISTRY
+
 
 @dataclasses.dataclass
 class PhysicalBatch:
@@ -33,13 +35,18 @@ class BatchMemoryManager:
     mask)``: when given, every physical batch is moved to its device (or
     mesh sharding) as it is produced, so host->device transfer overlaps the
     step instead of sitting on its critical path.
+
+    ``obs`` is the session's metrics registry: each physical batch's fetch
+    and placement run under the host spans ``fit/fetch`` and ``fit/place``
+    (no device value is watched, so no mode adds a sync).
     """
 
     def __init__(self, fetch: Callable[[np.ndarray], dict], physical: int,
-                 place: Optional[Callable] = None):
+                 place: Optional[Callable] = None, obs=NULL_REGISTRY):
         self.fetch = fetch
         self.p = physical
         self.place = place
+        self.obs = obs
 
     def batches(self, logical_indices: np.ndarray) -> Iterator[PhysicalBatch]:
         tl = len(logical_indices)
@@ -51,9 +58,11 @@ class BatchMemoryManager:
         mask[:tl] = 1.0
         for s in range(k):
             sl = slice(s * self.p, (s + 1) * self.p)
-            data, mk = self.fetch(padded[sl]), mask[sl]
+            with self.obs.span("fit/fetch"):
+                data, mk = self.fetch(padded[sl]), mask[sl]
             if self.place is not None:
-                data, mk = self.place(data, mk)
+                with self.obs.span("fit/place"):
+                    data, mk = self.place(data, mk)
             yield PhysicalBatch(
                 data=data,
                 mask=mk,

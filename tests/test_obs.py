@@ -204,6 +204,63 @@ def test_fit_emits_spans_and_dp_gauges(tmp_path):
     assert 0.0 <= stats["gauges"]["dp/clip_fraction"] <= 1.0
 
 
+def test_fit_emits_input_spans_under_accumulate(tmp_path):
+    """events mode: the input path's host spans (sampler draw, fetch,
+    placement) nest in fit/accumulate, one draw a step and one fetch and
+    placement a physical batch, none of them synced."""
+    p = str(tmp_path / "train.jsonl")
+    session = _dp_session(obs=ObsConfig(mode="events", jsonl=p))
+    session.fit()
+    session.obs.close()
+    spans = read_jsonl(p, kind="span")
+    by_name = {}
+    for r in spans:
+        by_name.setdefault(r["name"], []).append(r)
+    assert len(by_name["fit/sample"]) == 2
+    assert len(by_name["fit/fetch"]) == len(by_name["fit/place"]) >= 2
+    for name in ("fit/sample", "fit/fetch", "fit/place"):
+        assert {r["parent"] for r in by_name[name]} == {"fit/accumulate"}
+        assert not any(r["synced"] for r in by_name[name])
+
+
+def test_phase_scopes_refuse_unknown_names():
+    from repro.obs import PHASES, phase
+    assert len(PHASES) == 5
+    for name in PHASES:
+        with phase(name):
+            pass
+    with pytest.raises(ValueError, match="unknown phase"):
+        phase("grad/forward")
+
+
+def test_phase_scopes_name_the_accumulate_ops():
+    """Each engine's phases reach the op_name metadata of the lowered
+    accumulate: the per-example backward and the fold for masked_pe, the
+    norm pass and reweighted backward for masked_ghost, the batch
+    gradient for nonprivate."""
+    import re
+    want = {"masked_pe": {"grad/per_example", "grad/accumulate"},
+            "masked_ghost": {"grad/norm_pass", "grad/reweighted",
+                             "grad/accumulate"},
+            "nonprivate": {"grad/batch", "grad/accumulate"}}
+    for engine, phases in want.items():
+        dp = DPConfig(clip_norm=0.1, noise_multiplier=0.7, engine=engine)
+        tc = TrainConfig(steps=2, n_data=16, q=0.25, seq_len=8,
+                         physical_batch=4, seed=0, lr=0.1, optimizer="sgd",
+                         momentum=0.0)
+        session = PrivacySession.from_config("qwen2-0.5b", dp, tc)
+        session._configure_train()
+        batch = {"tokens": np.zeros((4, 8), np.int32),
+                 "labels": np.zeros((4, 8), np.int32)}
+        text = session._jitted("accumulate").lower(
+            session.state, batch, np.ones(4, np.float32)).as_text(
+                debug_info=True)
+        names = set(re.findall(r'loc\("jit\(accumulate\)/([^"]*)"', text))
+        seen = {ph for ph in phases
+                if any(n == ph or n.startswith(ph + "/") for n in names)}
+        assert seen == phases, (engine, sorted(names)[:20])
+
+
 def test_fit_surfaces_ckpt_wait(tmp_path, monkeypatch):
     """checkpoint_async stalls are timed, counted, and warned about when
     they exceed one mean step time."""
