@@ -183,9 +183,11 @@ def streaming_clipped_grads(loss_fn: Callable, params, batch, mask,
                                          axis=1)
                          if len(leaves) > 1 else leaves[0].reshape(m_eff, -1))
             if pad_d:
-                # pad the m-row tile (O(m·params)) to the accumulator layout;
-                # the accumulator itself is NEVER padded/copied here — that
-                # would break the kernel's input/output aliasing
+                # pad the m-row tile (O(m·params)) to the accumulator's
+                # length, FlatGradView's alignment tail; the kernel takes
+                # any flat length (a partial last D-block), so nothing pads
+                # to its tile.  The accumulator itself is NEVER padded or
+                # copied here — that would break the kernel's aliasing
                 tile_flat = jnp.pad(tile_flat, ((0, 0), (0, pad_d)))
             if kernel_map is None:
                 carry = flat_clip_accum(carry, tile_flat, norms, mk, clip_norm,

@@ -186,13 +186,83 @@ def test_clip_accum_inplace_padded_tail_stays_zero():
 
 
 def test_clip_accum_inplace_shape_errors():
+    """A flat length the D-block does not divide is a partial last block,
+    not an error; an accumulator off the grad row's length is."""
     from repro.kernels.clip_accum import clip_accum_inplace
     acc, g, norms, mask = _inplace_case(2, 300)
-    with pytest.raises(ValueError, match="must divide"):
-        clip_accum_inplace(acc, g, norms, mask, 1.0, interpret=True,
-                           tile_d=256)
+    out = clip_accum_inplace(acc, g, norms, mask, 1.0, interpret=True,
+                             tile_d=256)
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(ref.clip_accum_inplace_ref(acc, g, norms, mask, 1.0)))
     with pytest.raises(ValueError, match="acc shape"):
         clip_accum_inplace(acc[:256], g, norms, mask, 1.0, interpret=True)
+
+
+# (D, tile_d) with LANE_CHUNK = 8192: a partial last block of a block
+# narrower than one chunk; blocks of one chunk and a 512-lane tail chunk;
+# blocks of two chunks (the chunk loop) and a tail chunk
+@pytest.mark.parametrize("D,tile_d", [(300, 256), (1800, 512),
+                                      (18_408, 8_704), (38_792, 16_896)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("m", [1, 2, 8])
+def test_clip_accum_partial_block_bitwise(m, dtype, D, tile_d):
+    """D-blocks that do not divide D (a partial last block), blocks that
+    are no multiple of the lane chunk (a tail chunk) and chunks narrower
+    than the block fold every element in the same order as the
+    strict-fold oracle and as one whole-buffer block: BITWISE, for both
+    entry points and for f32 and bf16 rows."""
+    from repro.kernels.clip_accum import clip_accum_inplace
+    acc, g, norms, mask = _inplace_case(m, D, seed=m)
+    g = g.astype(dtype)
+    tiled = dict(interpret=True, tile_d=tile_d)
+    whole = dict(interpret=True, tile_d=D)
+    cases = [
+        (clip_accum_inplace(acc, g, norms, mask, 0.7, **tiled),
+         clip_accum_inplace(acc, g, norms, mask, 0.7, **whole),
+         ref.clip_accum_inplace_ref(acc, g, norms, mask, 0.7)),
+        (clip_accum(g, norms, mask, 0.7, **tiled),
+         clip_accum(g, norms, mask, 0.7, **whole),
+         ref.clip_accum_ref(g, norms, mask, 0.7)),
+    ]
+    for out, one_block, expect in cases:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(one_block))
+
+
+# flat accumulator lengths (FlatGradView totals) of the benchmark's models:
+# vit-base with its 100-class head, qwen2-0.5b with its untied head
+VIT_BASE_TOTAL = 85_875_712
+QWEN2_05B_TOTAL = 630_167_552
+
+
+@pytest.mark.parametrize("acc", [True, False])
+@pytest.mark.parametrize("itemsize", [4, 2])
+@pytest.mark.parametrize("rows", [1, 2, 8, 16, 32])
+@pytest.mark.parametrize("total", [VIT_BASE_TOTAL, QWEN2_05B_TOTAL])
+def test_pick_tile_d_fits_vmem_budget(total, rows, itemsize, acc):
+    """The tile rule is arithmetic on the call's shape: the block it picks
+    is whole lane chunks, no wider than the buffer, and its VMEM estimate
+    (double-buffered rows, padded flat rows, chunk scratch) stays within
+    the budget, so no call needs a raised VMEM limit."""
+    from repro.kernels.clip_accum import (LANE_CHUNK, VMEM_BUDGET,
+                                          pick_tile_d, vmem_bytes)
+    tile = pick_tile_d(total, rows, itemsize, acc=acc)
+    assert tile % LANE_CHUNK == 0 and LANE_CHUNK <= tile <= total
+    assert vmem_bytes(rows, itemsize, tile, LANE_CHUNK, acc) <= VMEM_BUDGET
+    # the next chunk would not fit: the block is the widest allowed
+    assert vmem_bytes(rows, itemsize, tile + LANE_CHUNK, LANE_CHUNK,
+                      acc) > VMEM_BUDGET
+
+
+def test_pick_tile_d_vit_stream_grid():
+    """At vit-base's flat length the streaming tile (m = 8 f32 rows) runs
+    a grid of at most 4096 programs, not one per 1024 lanes; a buffer
+    narrower than one block is one whole-buffer program."""
+    from repro.kernels.clip_accum import pick_tile_d
+    tile = pick_tile_d(VIT_BASE_TOTAL, 8, 4, acc=True)
+    assert -(-VIT_BASE_TOTAL // tile) <= 4096
+    assert pick_tile_d(300, 8, 4, acc=True) == 300
 
 
 def _tf_stream(seed, total):

@@ -68,23 +68,47 @@ def test_ghost_norm_dense_qwen2_width(one_chip):
     _assert_kernel(c)
 
 
+def _clip_accum(one_chip, n, rows=16, dtype=jnp.float32):
+    # the resident form over the unpadded parameter count: the D-block the
+    # VMEM budget picks for ``rows`` rows, with a partial last block
+    return _compile(one_chip,
+                    lambda g, nr, m: clip_accum(g, nr, m, 1.0,
+                                                interpret=False),
+                    ((rows, n), dtype), ((rows,), jnp.float32),
+                    ((rows,), jnp.float32))
+
+
+def _clip_accum_inplace(one_chip, total, m=8, dtype=jnp.float32):
+    # the streaming form over the aligned flat length, m-row tile
+    return _compile(one_chip,
+                    lambda a, g, nr, k: clip_accum_inplace(a, g, nr, k, 1.0,
+                                                           interpret=False),
+                    ((total,), jnp.float32), ((m, total), dtype),
+                    ((m,), jnp.float32), ((m,), jnp.float32))
+
+
 def test_clip_accum_vit_flat_length(one_chip, vit_flat):
     n, _ = vit_flat
-    c = _compile(one_chip,
-                 lambda g, nr, m: clip_accum(g, nr, m, 1.0, interpret=False),
-                 ((16, n), jnp.float32), ((16,), jnp.float32),
-                 ((16,), jnp.float32))
-    _assert_kernel(c)
+    _assert_kernel(_clip_accum(one_chip, n))
+
+
+@pytest.mark.parametrize("rows,dtype", [(16, jnp.bfloat16),
+                                        (32, jnp.float32)])
+def test_clip_accum_vit_flat_length_rows(one_chip, vit_flat, rows, dtype):
+    n, _ = vit_flat
+    _assert_kernel(_clip_accum(one_chip, n, rows, dtype))
 
 
 def test_clip_accum_inplace_vit_flat_length(one_chip, vit_flat):
     _, total = vit_flat
-    c = _compile(one_chip,
-                 lambda a, g, nr, m: clip_accum_inplace(a, g, nr, m, 1.0,
-                                                        interpret=False),
-                 ((total,), jnp.float32), ((8, total), jnp.float32),
-                 ((8,), jnp.float32), ((8,), jnp.float32))
-    _assert_kernel(c)
+    _assert_kernel(_clip_accum_inplace(one_chip, total))
+
+
+@pytest.mark.parametrize("m,dtype", [(8, jnp.bfloat16), (32, jnp.float32)])
+def test_clip_accum_inplace_vit_flat_length_rows(one_chip, vit_flat, m,
+                                                 dtype):
+    _, total = vit_flat
+    _assert_kernel(_clip_accum_inplace(one_chip, total, m, dtype))
 
 
 @pytest.mark.parametrize("momentum", [False, True])
