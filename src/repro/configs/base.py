@@ -31,12 +31,24 @@ class ArchConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    n_router_experts: int = 0        # router width; 0: equal to n_experts.
+    expert_offset: int = 0           # with it, n_experts counts the experts
+                                     # held, [offset, offset + n_experts)
+    norm_topk_prob: bool = True      # renormalise the top-k gates to sum 1
+    router_dtype: str = ""           # router logits' dtype; "": activations'
     moe_d_ff: int = 0                # per-expert hidden (if != d_ff)
     first_dense_layers: int = 0      # deepseek: leading dense FFN layers
     dense_d_ff: int = 0              # FFN width of those leading dense layers
     # MLA (deepseek-v2)
     kv_lora: int = 0
     rope_dim: int = 0
+    # YaRN rope scaling (deepseek-v2); factor 0: plain RoPE
+    rope_scaling_factor: float = 0.0
+    rope_original_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # SSM (mamba2 / zamba2)
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -67,6 +79,11 @@ class ArchConfig:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
     @property
+    def n_router(self) -> int:
+        """Experts the router chooses among (all of them, held or not)."""
+        return self.n_router_experts or self.n_experts
+
+    @property
     def act_dtype(self):
         return jnp.dtype(self.dtype)
 
@@ -85,6 +102,7 @@ class ArchConfig:
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads < self.n_heads else 4,
             d_ff=256, vocab=97, head_dim=32,
             n_experts=4 if self.n_experts else 0,
+            n_router_experts=0, expert_offset=0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             moe_d_ff=64 if self.n_experts else 0,
             n_shared_experts=min(self.n_shared_experts, 1),

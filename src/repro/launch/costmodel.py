@@ -42,8 +42,8 @@ class Costs:
 
 
 def param_stats(model, cfg: ArchConfig):
-    """Exact param counts from the tree (experts discounted by top_k/E for
-    the active count)."""
+    """Exact param counts from the tree (experts discounted by top_k/E, E
+    the router's width, for the active count)."""
     shapes = jax.eval_shape(                   # lint: allow-const-key
         lambda: model.init(jax.random.PRNGKey(0)))
     flat = flatten_params(shapes)
@@ -53,7 +53,7 @@ def param_stats(model, cfg: ArchConfig):
         n = float(math.prod(leaf.shape))
         total += n
         if ".moe.w" in path.replace("/", "."):
-            active += n * (cfg.top_k / max(cfg.n_experts, 1))
+            active += n * (cfg.top_k / max(cfg.n_router, 1))
         else:
             active += n
     return total, active, flat
@@ -69,9 +69,10 @@ def _dense_fwd_flops(flat, cfg: ArchConfig, tokens: float) -> float:
         stack = math.prod(sh[:-2]) if len(sh) > 2 else 1
         i, o = sh[-2], sh[-1]
         if ".moe.w" in path:
-            # stacked (L, E, i, o): each expert sees tokens·K·cf/E rows
+            # stacked (L, E, i, o): each expert sees tokens·K·cf/E rows,
+            # E the router's width (the layer may hold a share of them)
             L = math.prod(sh[:-3]) if len(sh) > 3 else 1
-            rows = tokens * cfg.top_k * cfg.capacity_factor / cfg.n_experts
+            rows = tokens * cfg.top_k * cfg.capacity_factor / cfg.n_router
             f += 2 * L * cfg.n_experts * rows * i * o
         elif path.startswith("emb"):
             continue        # gather, not matmul
@@ -130,7 +131,7 @@ def _ghost_norm_flops(flat, cfg: ArchConfig, B: float, T: float) -> float:
         i, o = sh[-2], sh[-1]
         Te = T
         if ".moe.w" in path:
-            Te = T * cfg.top_k * cfg.capacity_factor / max(cfg.n_experts, 1)
+            Te = T * cfg.top_k * cfg.capacity_factor / max(cfg.n_router, 1)
             stack = math.prod(sh[:-2])
         f += stack * B * min(2 * Te * Te * (i + o), 2 * Te * i * o)
     # embedding ghost: B·T²·d

@@ -6,6 +6,7 @@ architecture is ghost/BK-clippable without per-arch DP code.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -68,13 +69,52 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x (..., T, H, Dh), positions (..., T) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature 0.1·mscale·ln(factor) + 1 (1 when the
+    context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_max_pos: int,
+                          beta_fast: float, beta_slow: float):
+    """The rotary pairs (low, high) between which YaRN ramps: pair i turns
+    original_max_pos·theta^(-2i/dim)/(2π) times over the original context;
+    those turning more than ``beta_fast`` times end at ``low``, those
+    turning fewer than ``beta_slow`` times start at ``high``."""
+    def pair(rotations):
+        return (dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), dim - 1))
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original_max_pos: int,
+               beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies (arXiv:2309.00071, DeepSeek-V2's form):
+    pairs up to ``low`` keep theirs, pairs from ``high`` on are divided by
+    ``factor``, and those between follow the linear ramp."""
+    low, high = yarn_correction_range(dim, theta, original_max_pos,
+                                      beta_fast, beta_slow)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta: float, freqs=None, mscale: float = 1.0):
+    """x (..., T, H, Dh), positions (..., T) int32; ``freqs`` (Dh/2,)
+    overrides the plain inverse frequencies and ``mscale`` multiplies
+    cos and sin (YaRN)."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)                         # (Dh/2,)
+    if freqs is None:
+        freqs = rope_freqs(dh, theta)                     # (Dh/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., T, Dh/2)
     cos = jnp.cos(ang)[..., None, :]                      # (..., T, 1, Dh/2)
     sin = jnp.sin(ang)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -202,12 +242,14 @@ def attn_params(key, d_model: int, a: AttnCfg):
     return p
 
 
-def _sdpa(q, k, v, mask):
-    """q (B,T,Hkv,G,Dh), k/v (B,S,Hkv,Dh), mask (B,T,S) or (T,S) bool.
+def _sdpa(q, k, v, mask, scale: Optional[float] = None):
+    """q (B,T,Hkv,G,Dh), k/v (B,S,Hkv,Dh), mask (B,T,S) or (T,S) bool;
+    the scores take ``scale`` (default Dh^-1/2).
 
     Inputs stay in their storage dtype (bf16 on TPU); the MXU accumulates in
     f32 via preferred_element_type — no f32 copies of the KV cache."""
-    scale = jnp.asarray(q.shape[-1] ** -0.5, q.dtype)
+    scale = jnp.asarray(q.shape[-1] ** -0.5 if scale is None else scale,
+                        q.dtype)
     s = jnp.einsum("btkgd,bskd->bktgs", q * scale, k,
                    preferred_element_type=jnp.float32)
     if mask.ndim == 2:

@@ -4,6 +4,11 @@ MLA compresses K/V into a rank-``kv_lora`` latent c plus a small shared RoPE
 key.  The decode cache stores only (c, k_rope) — (kv_lora + rope_dim) floats
 per token instead of 2·H·Dh — and decoding uses the absorbed-matmul form
 (scores against c directly), which is the arch's whole point.
+
+Rotary positions may be YaRN-scaled (:func:`rope_scaling`).  The rope dims
+rotate in the half-split form; the published model de-interleaves each
+head's rope pairs first, which is the same up to a fixed permutation of
+the rope columns of ``wq`` and ``wkr``.
 """
 from __future__ import annotations
 
@@ -13,6 +18,7 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig
 from ..core import layers as L
 from ..core.tape import Tape, scan_blocks
+from ..obs.phases import block
 from . import common as cm
 from .moe import moe_block, moe_params
 
@@ -40,12 +46,38 @@ def _dims(cfg: ArchConfig):
     return nope, vh, cfg.rope_dim
 
 
+def rope_scaling(cfg: ArchConfig):
+    """(inverse frequencies of the rope dims or None for plain RoPE, the
+    cos/sin factor, the softmax scale).  With YaRN (``rope_scaling_factor``
+    set) the frequencies ramp between the correction range's ends, cos
+    and sin take mscale(factor, mscale) / mscale(factor, mscale_all_dim),
+    and the scale (nope + rope)^-1/2 takes mscale(factor, mscale_all_dim)²,
+    as DeepSeek-V2 does."""
+    nope, _, rd = _dims(cfg)
+    scl = (nope + rd) ** -0.5
+    f = cfg.rope_scaling_factor
+    if not f:
+        return None, 1.0, scl
+    freqs = cm.yarn_freqs(rd, cfg.rope_theta, f, cfg.rope_original_max_pos,
+                          cfg.rope_beta_fast, cfg.rope_beta_slow)
+    all_dim = cm.yarn_mscale(f, cfg.rope_mscale_all_dim)
+    if cfg.rope_mscale_all_dim:
+        scl = scl * all_dim * all_dim
+    return freqs, cm.yarn_mscale(f, cfg.rope_mscale) / all_dim, scl
+
+
 def mla_attention(tape: Tape, scope: str, path: str, p, x, cfg: ArchConfig,
                   positions):
     """Training/prefill MLA (full sequence, causal)."""
+    with block("mla/attention"):
+        return _mla_attention(tape, scope, path, p, x, cfg, positions)
+
+
+def _mla_attention(tape, scope, path, p, x, cfg, positions):
     B, T, D = x.shape
     H = cfg.n_heads
     nope, vh, rd = _dims(cfg)
+    freqs, rope_m, scl = rope_scaling(cfg)
 
     q = L.dense(tape, f"{scope}.wq", x, p["wq"]["w"], param_path=f"{path}.wq")
     q = q.reshape(B, T, H, nope + rd)
@@ -61,20 +93,18 @@ def mla_attention(tape: Tape, scope: str, path: str, p, x, cfg: ArchConfig,
     k_rope = L.dense(tape, f"{scope}.wkr", x, p["wkr"]["w"],
                      param_path=f"{path}.wkr").reshape(B, T, 1, rd)
 
-    q_rope = cm.apply_rope(q_rope, positions, cfg.rope_theta)
-    k_rope = cm.apply_rope(k_rope, positions, cfg.rope_theta)
+    q_rope = cm.apply_rope(q_rope, positions, cfg.rope_theta, freqs, rope_m)
+    k_rope = cm.apply_rope(k_rope, positions, cfg.rope_theta, freqs, rope_m)
 
-    scl = (nope + rd) ** -0.5
-    s = (jnp.einsum("bthd,bshd->bhts", q_nope.astype(jnp.float32),
-                    k_nope.astype(jnp.float32))
-         + jnp.einsum("bthd,bsxd->bhts", q_rope.astype(jnp.float32),
-                      jnp.broadcast_to(k_rope, (B, T, 1, rd)).astype(jnp.float32))) * scl
+    # one head of (nope + rope) dims against the shared rope key: the
+    # dense layers' attention, at MLA's own softmax scale
+    qh = jnp.concatenate([q_nope, q_rope], axis=-1)
+    kh = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, T, H, rd))],
+                         axis=-1)
     mask = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-    s = jnp.where(mask[None, None], s, -1e30)
-    a = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhts,bshd->bthd", a, v.astype(jnp.float32)).astype(x.dtype)
-    return L.dense(tape, f"{scope}.wo", o.reshape(B, T, H * vh), p["wo"]["w"],
-                   param_path=f"{path}.wo")
+    o = cm._sdpa(qh[:, :, :, None], kh, v, mask, scale=scl)   # (B,T,H,1,vh)
+    return L.dense(tape, f"{scope}.wo", o.reshape(B, T, H * vh),
+                   p["wo"]["w"], param_path=f"{path}.wo")
 
 
 def mla_decode(p, x, cfg: ArchConfig, cache, pos, valid=None):
@@ -83,23 +113,29 @@ def mla_decode(p, x, cfg: ArchConfig, cache, pos, valid=None):
     ``pos`` is a scalar or a (B,) vector of per-slot start positions;
     ``valid`` (B,T) masks unconsumed chunk-tail tokens (their cache writes
     are dropped)."""
+    with block("mla/attention"):
+        return _mla_decode(p, x, cfg, cache, pos, valid)
+
+
+def _mla_decode(p, x, cfg, cache, pos, valid):
     B, T, D = x.shape
     H = cfg.n_heads
     nope, vh, rd = _dims(cfg)
     r = cfg.kv_lora
+    freqs, rope_m, scl = rope_scaling(cfg)
     posb = cm.decode_positions(pos, B)                     # (B,)
     tok_pos = posb[:, None] + jnp.arange(T, dtype=jnp.int32)   # (B,T)
 
     q = (x @ p["wq"]["w"]).reshape(B, T, H, nope + rd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = cm.apply_rope(q_rope, tok_pos, cfg.rope_theta)
+    q_rope = cm.apply_rope(q_rope, tok_pos, cfg.rope_theta, freqs, rope_m)
 
     c1 = x @ p["wdkv"]["w"]
     c1f = c1.astype(jnp.float32)
     c1 = (c1f * jax.lax.rsqrt(jnp.mean(c1f * c1f, -1, keepdims=True) + 1e-6)
           ).astype(x.dtype) * p["ckv_norm"]["w"].astype(x.dtype)
     kr1 = (x @ p["wkr"]["w"]).reshape(B, T, 1, rd)
-    kr1 = cm.apply_rope(kr1, tok_pos, cfg.rope_theta)
+    kr1 = cm.apply_rope(kr1, tok_pos, cfg.rope_theta, freqs, rope_m)
 
     cc = cm.scatter_rows(cache["c"], tok_pos, c1, valid)
     ckr = cm.scatter_rows(cache["kr"], tok_pos, kr1[:, :, 0], valid)
@@ -112,7 +148,7 @@ def mla_decode(p, x, cfg: ArchConfig, cache, pos, valid=None):
                      w_uk.astype(jnp.float32))
     s = (jnp.einsum("bthr,bsr->bhts", q_c, cc.astype(jnp.float32))
          + jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32),
-                      ckr.astype(jnp.float32))) * (nope + rd) ** -0.5
+                      ckr.astype(jnp.float32))) * scl
     vis = jnp.arange(S)[None, None, :] <= tok_pos[:, :, None]  # (B,T,S)
     s = jnp.where(vis[:, None], s, -1e30)
     a = jax.nn.softmax(s, axis=-1)
@@ -149,7 +185,7 @@ class DeepseekV2LM:
                     "attn": mla_params(k1, cfg),
                     "ln2": cm.norm_params(cfg.d_model),
                     "moe": moe_params(k2, cfg.d_model, cfg.n_experts,
-                                      cfg.moe_d_ff or cfg.d_ff),
+                                      cfg.moe_d_ff or cfg.d_ff, cfg.n_router),
                     "shared": cm.swiglu_params(k3, cfg.d_model, shared_ff)}
 
         nd = cfg.first_dense_layers

@@ -20,14 +20,18 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig
 from ..core import layers as L
 from ..core.tape import Tape, scan_blocks
+from ..obs.phases import block
 from . import common as cm
 
 
-def moe_params(key, d_model: int, n_experts: int, d_ff: int):
+def moe_params(key, d_model: int, n_experts: int, d_ff: int,
+               n_router: int):
+    """The router over ``n_router`` experts and the ``n_experts`` held
+    experts' stacked SwiGLU weights."""
     ks = jax.random.split(key, 4)
     s = d_model ** -0.5
     return {
-        "router": cm.dense_params(ks[0], d_model, n_experts, scale=s),
+        "router": cm.dense_params(ks[0], d_model, n_router, scale=s),
         "w1": {"w": jax.random.normal(ks[1], (n_experts, d_model, d_ff)) * s},
         "w3": {"w": jax.random.normal(ks[2], (n_experts, d_model, d_ff)) * s},
         "w2": {"w": jax.random.normal(ks[3], (n_experts, d_ff, d_model)) * d_ff ** -0.5},
@@ -38,6 +42,15 @@ def moe_block(tape: Tape, scope: str, path: str, p, x, cfg: ArchConfig,
               min_cap: int = 1):
     """x (B,T,D) -> (out (B,T,D), aux_loss (B,)).
 
+    The layer holds ``cfg.n_experts`` experts, those numbered
+    ``cfg.expert_offset`` on, of the ``cfg.n_router`` the router chooses
+    among (an expert-parallel deployment's share; all of them by default).
+    Routing is over the router's full width; dispatch, the expert matmuls
+    and the combine cover the held experts only, so ``out`` is their part
+    of the layer's result.  The capacity of an expert is per example,
+    ceil(T·K·cf / n_router), so each row's output depends on that row
+    alone.
+
     ``min_cap`` floors the per-expert capacity: chunked decode passes the
     chunk length T so no token is ever dropped (top-k picks distinct experts
     per token, so an expert receives at most T assignments per row) — a
@@ -45,55 +58,66 @@ def moe_block(tape: Tape, scope: str, path: str, p, x, cfg: ArchConfig,
     it arrived inside a prefill chunk, or chunked serving would diverge from
     per-token decoding."""
     B, T, D = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    cap = max(min_cap, math.ceil(T * K * cfg.capacity_factor / E))
+    E, ER, K, off = cfg.n_experts, cfg.n_router, cfg.top_k, cfg.expert_offset
+    cap = max(min_cap, math.ceil(T * K * cfg.capacity_factor / ER))
 
-    logits = L.dense(tape, f"{scope}.router", x, p["router"]["w"],
-                     param_path=f"{path}.router")
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)   # (B,T,E)
-    topv, topi = jax.lax.top_k(probs, K)                          # (B,T,K)
-    topv = topv / (topv.sum(-1, keepdims=True) + 1e-9)
+    with block("moe/route"):
+        # a router dtype of its own is computed at full precision (a TPU
+        # multiplies float32 in one bfloat16 pass by default)
+        xr, prec = x, None
+        if cfg.router_dtype:
+            xr, prec = x.astype(cfg.router_dtype), jax.lax.Precision.HIGHEST
+        logits = L.dense(tape, f"{scope}.router", xr, p["router"]["w"],
+                         param_path=f"{path}.router", precision=prec)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # (B,T,ER)
+        topv, topi = jax.lax.top_k(probs, K)                        # (B,T,K)
+        if cfg.norm_topk_prob:
+            topv = topv / (topv.sum(-1, keepdims=True) + 1e-9)
 
-    # ---- position-in-expert over the T*K virtual-token axis ----
-    e_flat = topi.reshape(B, T * K)
-    oh = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)               # (B,TK,E)
-    pos = jnp.cumsum(oh, axis=1) - oh                              # exclusive
-    pos = jnp.take_along_axis(pos, e_flat[..., None], -1)[..., 0]  # (B,TK)
-    valid = pos < cap
-    idx = jnp.where(valid, e_flat * cap + pos, E * cap)            # E*cap = drop
+        # ---- position-in-expert over the T*K virtual-token axis ----
+        e_flat = topi.reshape(B, T * K)
+        oh = jax.nn.one_hot(e_flat, ER, dtype=jnp.int32)            # (B,TK,ER)
+        pos = jnp.cumsum(oh, axis=1) - oh                            # exclusive
+        pos = jnp.take_along_axis(pos, e_flat[..., None], -1)[..., 0]  # (B,TK)
+        local = e_flat - off
+        valid = (pos < cap) & (local >= 0) & (local < E)
+        idx = jnp.where(valid, local * cap + pos, E * cap)           # E*cap = drop
+
+        # ---- per-example load-balance aux loss over the whole router ----
+        f = oh.astype(jnp.float32).mean(axis=1)                      # (B,ER)
+        pmean = probs.mean(axis=1)                                   # (B,ER)
+        aux = ER * jnp.sum(f * pmean, axis=-1) * cfg.router_aux_coef  # (B,)
 
     # ---- dispatch: scatter tokens into per-expert capacity buffers ----
-    x_rep = jnp.repeat(x, K, axis=1)                               # (B,TK,D)
+    with block("moe/dispatch"):
+        x_rep = jnp.repeat(x, K, axis=1)                             # (B,TK,D)
 
-    def scatter_one(xi, ii):
-        return jnp.zeros((E * cap, D), x.dtype).at[ii].add(
-            xi, mode="drop")
-    buf = jax.vmap(scatter_one)(x_rep, idx)                        # (B,E*cap,D)
-    buf = buf.reshape(B, E, cap, D).transpose(1, 0, 2, 3)          # (E,B,cap,D)
-    buf = cm.maybe_shard_expert(buf)
+        def scatter_one(xi, ii):
+            return jnp.zeros((E * cap, D), x.dtype).at[ii].add(
+                xi, mode="drop")
+        buf = jax.vmap(scatter_one)(x_rep, idx)                      # (B,E*cap,D)
+        buf = buf.reshape(B, E, cap, D).transpose(1, 0, 2, 3)        # (E,B,cap,D)
+        buf = cm.maybe_shard_expert(buf)
 
     # ---- expert computation (stacked over E -> expert parallel) ----
-    # w1/w3 share the dispatch buffer: record it once (halves MoE records)
-    g, u = L.dense_stacked_pair(tape, f"{scope}.w13", buf,
-                                p["w1"]["w"], p["w3"]["w"],
-                                param_path1=f"{path}.w1",
-                                param_path2=f"{path}.w3")
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-    yb = L.dense_stacked(tape, f"{scope}.w2", h, p["w2"]["w"],
-                         param_path=f"{path}.w2")                  # (E,B,cap,D)
+    with block("moe/experts"):
+        # w1/w3 share the dispatch buffer: record it once (halves MoE records)
+        g, u = L.dense_stacked_pair(tape, f"{scope}.w13", buf,
+                                    p["w1"]["w"], p["w3"]["w"],
+                                    param_path1=f"{path}.w1",
+                                    param_path2=f"{path}.w3")
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        yb = L.dense_stacked(tape, f"{scope}.w2", h, p["w2"]["w"],
+                             param_path=f"{path}.w2")                # (E,B,cap,D)
 
     # ---- combine: gather back, weight by gates ----
-    yb = yb.transpose(1, 0, 2, 3).reshape(B, E * cap, D)
-    gathered = jnp.take_along_axis(
-        yb, jnp.minimum(idx, E * cap - 1)[..., None], axis=1)      # (B,TK,D)
-    w = (topv.reshape(B, T * K) * valid.astype(jnp.float32))
-    y = (gathered.astype(jnp.float32) * w[..., None]).reshape(B, T, K, D)
-    y = y.sum(axis=2).astype(x.dtype)
-
-    # ---- per-example load-balance aux loss (Switch-style) ----
-    f = oh.astype(jnp.float32).mean(axis=1)                        # (B,E)
-    pmean = probs.mean(axis=1)                                     # (B,E)
-    aux = E * jnp.sum(f * pmean, axis=-1) * cfg.router_aux_coef    # (B,)
+    with block("moe/combine"):
+        yb = yb.transpose(1, 0, 2, 3).reshape(B, E * cap, D)
+        gathered = jnp.take_along_axis(
+            yb, jnp.minimum(idx, E * cap - 1)[..., None], axis=1)    # (B,TK,D)
+        w = (topv.reshape(B, T * K) * valid.astype(jnp.float32))
+        y = (gathered.astype(jnp.float32) * w[..., None]).reshape(B, T, K, D)
+        y = y.sum(axis=2).astype(x.dtype)
     return y, aux
 
 
@@ -117,7 +141,7 @@ class MoeLM:
                     "attn": cm.attn_params(k1, cfg.d_model, self.acfg),
                     "ln2": cm.norm_params(cfg.d_model),
                     "moe": moe_params(k2, cfg.d_model, cfg.n_experts,
-                                      cfg.moe_d_ff or cfg.d_ff)}
+                                      cfg.moe_d_ff or cfg.d_ff, cfg.n_router)}
 
         return {
             "emb": {"w": jax.random.normal(ks[0], (cfg.vocab, cfg.d_model)) * 0.02},

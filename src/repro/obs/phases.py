@@ -27,17 +27,46 @@ agree on it:
 Scopes wrap call sites in ``core/``, never code inside ``kernels/``: a
 scope between ``jit(<kernel>)`` and ``pallas_call`` would change the
 kernel's ``op_name`` path, which is how the kernel is found in the HLO.
+
+A second fixed vocabulary, :data:`BLOCKS`, names the model blocks whose
+time is worth splitting inside a phase; the model code wraps them with
+:func:`block`.  A block scope sits inside whichever phase runs the model
+(the per-example backward, the norm pass, the reweighted backward), so it
+never changes the phase an instruction is credited to:
+
+* ``mla/attention`` — latent attention (:func:`repro.models.mla.mla_attention`
+  and its decode form);
+* ``moe/route`` — the router's logits, softmax, top-k, the capacity
+  positions and the load-balance term;
+* ``moe/dispatch`` — tokens scattered into the held experts' capacity
+  buffers;
+* ``moe/experts`` — the held experts' SwiGLU matmuls;
+* ``moe/combine`` — the experts' outputs gathered back and weighted by
+  the gates.
 """
 from __future__ import annotations
 
 PHASES = ("grad/per_example", "grad/norm_pass", "grad/reweighted",
           "grad/batch", "grad/accumulate")
 
+BLOCKS = ("mla/attention", "moe/route", "moe/dispatch", "moe/experts",
+          "moe/combine")
+
+
+def _scope(name: str, vocabulary, kind: str):
+    if name not in vocabulary:
+        raise ValueError(f"unknown {kind} {name!r}; {kind}s: {vocabulary}")
+    import jax
+    return jax.named_scope(name)
+
 
 def phase(name: str):
     """``jax.named_scope(name)`` for a phase of :data:`PHASES`; any other
     name is refused."""
-    if name not in PHASES:
-        raise ValueError(f"unknown phase {name!r}; phases: {PHASES}")
-    import jax
-    return jax.named_scope(name)
+    return _scope(name, PHASES, "phase")
+
+
+def block(name: str):
+    """``jax.named_scope(name)`` for a model block of :data:`BLOCKS`; any
+    other name is refused."""
+    return _scope(name, BLOCKS, "block")
